@@ -369,7 +369,12 @@ def _spawn_measured(mode: str, src: str, dst: str) -> dict:
         "--stream-chunk-mib", str(STREAM_CHUNK_MIB),
         "--stream-window", str(STREAM_WINDOW),
     ]
-    p = subprocess.Popen(cmd, stdout=subprocess.PIPE, cwd=RESULTS_DIR.parent)
+    # host-only rows: a child must never reach for the chip, which the
+    # parent may already hold (the engine section's device rows run first)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.Popen(
+        cmd, stdout=subprocess.PIPE, cwd=RESULTS_DIR.parent, env=env
+    )
     out = p.stdout.read()
     _pid, status, ru = os.wait4(p.pid, 0)
     p.returncode = os.waitstatus_to_exitcode(status)
@@ -493,7 +498,7 @@ def run_serve(emit_json: bool = False, print_rows: bool = True):
         src = os.path.join(tmp, "corpus.log")
         with open(src, "wb") as f:
             f.write(corpus)
-        env = dict(os.environ)
+        env = dict(os.environ, JAX_PLATFORMS="cpu")  # host CLI: off the chip
         env["PYTHONPATH"] = str(RESULTS_DIR.parent / "src") + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
         )

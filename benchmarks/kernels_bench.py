@@ -1,4 +1,4 @@
-"""Pallas kernel micro-benchmarks: us/call in interpret mode (CPU) for the
+"""Pallas kernel micro-benchmarks: us/call (interpret mode off the TPU) for the
 kernel and its jnp oracle, plus the fused-vs-unfused HBM-traffic model for
 K1 (numbers feed EXPERIMENTS.md §Perf/K1)."""
 from __future__ import annotations
@@ -28,14 +28,14 @@ def run(print_rows: bool = True):
     x = jnp.asarray(np.cumsum(rng.integers(0, 200, N)).astype(np.uint32))
     planes = jnp.asarray(rng.integers(0, 256, (N, 4)), jnp.uint8)
     rows = []
-    rows.append(("delta_encode_pallas", _time(lambda a: ops.delta_encode(a), x)))
+    rows.append(("delta_encode_pallas", _time(lambda a: ops.delta_encode(a, use_pallas=True), x)))
     rows.append(("delta_encode_ref", _time(lambda a: ops.delta_encode(a, use_pallas=False), x)))
-    rows.append(("delta_decode_pallas", _time(lambda a: ops.delta_decode(a), x)))
-    rows.append(("byteshuffle_pallas", _time(lambda a: ops.byteshuffle(a), planes)))
-    rows.append(("bitpack8_pallas", _time(lambda a: ops.bitpack(a & 0xFF, 8), x)))
-    rows.append(("histogram_pallas", _time(lambda a: ops.histogram(a.astype(jnp.uint8)), x)))
-    rows.append(("float_split_pallas", _time(lambda a: ops.float_split(a, 8, 23)[2], x)))
-    rows.append(("fused_delta_bitpack", _time(lambda a: ops.fused_delta_bitpack(a, 8), x)))
+    rows.append(("delta_decode_pallas", _time(lambda a: ops.delta_decode(a, use_pallas=True), x)))
+    rows.append(("byteshuffle_pallas", _time(lambda a: ops.byteshuffle(a, use_pallas=True), planes)))
+    rows.append(("bitpack8_pallas", _time(lambda a: ops.bitpack(a & 0xFF, 8, use_pallas=True), x)))
+    rows.append(("histogram_exact", _time(lambda a: ops.histogram_exact(a), x)))
+    rows.append(("float_split_pallas", _time(lambda a: ops.float_split(a, 8, 23, use_pallas=True)[2], x)))
+    rows.append(("fused_delta_bitpack", _time(lambda a: ops.fused_delta_bitpack(a, 8, use_pallas=True), x)))
 
     # K1 HBM-traffic model (bytes moved per element, bits=8):
     #   unfused: delta(read 4 + write 4) + pack(read 4 + write 1) = 13 B/elt

@@ -91,7 +91,16 @@ def _load_compressor(args) -> Compressor:
 
 
 # --------------------------------------------------------------- subcommands
+def _use_compile_cache_for(backend: Optional[str]) -> None:
+    """On the device backend, keep compiled kernels across runs."""
+    if backend == "device":
+        from repro.device import use_compile_cache
+
+        use_compile_cache()
+
+
 def _cmd_compress(args) -> int:
+    _use_compile_cache_for(args.backend)
     src = Path(args.input)
     dst = Path(args.output) if args.output else src.with_name(src.name + ".ozl")
     comp = _load_compressor(args)
@@ -496,8 +505,14 @@ def _cmd_serve(args) -> int:
 
     import socket as _socket
 
+    from repro.service.plane import check_device_workers
     from repro.service.protocol import parse_address
 
+    try:
+        check_device_workers(args.workers, args.backend)
+    except ValueError as err:
+        raise SystemExit(f"serve: {err}") from None
+    _use_compile_cache_for(args.backend)
     spec = _service_address(args)  # exactly one of --socket / --tcp
     try:
         family, target = parse_address(spec)
@@ -772,7 +787,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "overloaded + retry_after); default: block instead")
     s.add_argument("--backend", default=None,
                    help="execution backend for every pooled session (host/"
-                        "device); faulting device backends fail over to host")
+                        "device); faulting device backends fail over to host;"
+                        " device allows at most one worker process")
     s.set_defaults(fn=_cmd_serve)
 
     cl = sub.add_parser("client", help="talk to a running compression daemon")

@@ -83,14 +83,6 @@ def device_available() -> bool:
     return _JAX_OK
 
 
-def device_use_pallas() -> bool:
-    """Real Mosaic kernels on TPU; the jit'd jnp oracle elsewhere (Pallas
-    interpret mode is a correctness tool, far too slow for the data path)."""
-    import jax
-
-    return jax.default_backend() == "tpu"
-
-
 def min_uint_width(max_value: int) -> int:
     if max_value < 1 << 8:
         return 1

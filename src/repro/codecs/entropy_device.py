@@ -21,7 +21,7 @@ import numpy as np
 from repro.core.codec import register_backend_codec
 from repro.core.message import Stream, SType
 
-from ._util import HeaderWriter, device_available, device_use_pallas, numeric_stream
+from ._util import HeaderWriter, device_available, numeric_stream
 from .entropy import (
     BLOCK_LOG,
     FSE_BLOCK_LOG,
@@ -73,12 +73,11 @@ def _huffman_enc_device(streams, params):
     x = _as_u8(streams[0], "huffman")
     n = x.size
     xj = jnp.asarray(x)
-    up = device_use_pallas()
     counts = np.asarray(ops.histogram_exact(xj)).astype(np.int64)
     lens = _huffman_code_lengths(counts)
     codes = _huffman_codes_cached(lens)
     code, _nb, offs = ops.huffman_map(
-        xj, jnp.asarray(codes), jnp.asarray(lens.astype(np.int32)), use_pallas=up
+        xj, jnp.asarray(codes), jnp.asarray(lens.astype(np.int32))
     )
     total = int(offs[-1])
     total_bytes = (total + 7) >> 3
@@ -116,7 +115,6 @@ def _fse_enc_device(streams, params):
     table_log = int(params.get("table_log", 11))
     stype_tag = int(streams[0].stype)
     xj = jnp.asarray(x)
-    up = device_use_pallas()
     counts = np.asarray(ops.histogram_exact(xj)).astype(np.int64)
     norm = _normalize_counts(counts, table_log)
     _ds, _dn, _db, enc_table, nb0t, thrt, st0t = _fse_tables_cached(norm, table_log)
@@ -141,7 +139,6 @@ def _fse_enc_device(streams, params):
         jnp.asarray(enc_table.reshape(-1)),
         width,
         total,
-        use_pallas=up,
     )
     total_bytes = int(byte_off[-1])
     stream_out = np.asarray(

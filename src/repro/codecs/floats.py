@@ -30,7 +30,6 @@ from ._util import (
     HeaderReader,
     HeaderWriter,
     device_available,
-    device_use_pallas,
     numeric_stream,
 )
 
@@ -152,9 +151,7 @@ def _float_split_enc_device(streams, params):
     fmt = 2
     _width, exp_bits, man_bits = FORMATS[fmt]
     u = s.data.view(np.uint32)
-    sign, exp, man = ops.float_split(
-        jnp.asarray(u), exp_bits, man_bits, use_pallas=device_use_pallas()
-    )
+    sign, exp, man = ops.float_split(jnp.asarray(u), exp_bits, man_bits)
     h = HeaderWriter().u8(fmt).varint(u.size).done()
     return [
         Stream(_pack_sign_bits(np.asarray(sign, np.uint8)), SType.SERIAL, 1),

@@ -33,7 +33,6 @@ from ._util import (
     HeaderReader,
     HeaderWriter,
     device_available,
-    device_use_pallas,
     min_uint_width,
     numeric_stream,
 )
@@ -569,10 +568,7 @@ def _delta_enc_device(streams, params):
     s = streams[0]
     x = s.data.view(UNSIGNED[s.width])
     d32 = np.asarray(
-        ops.delta_encode(
-            jnp.asarray(x.astype(np.uint32, copy=False)),
-            use_pallas=device_use_pallas(),
-        )
+        ops.delta_encode(jnp.asarray(x.astype(np.uint32, copy=False)))
     )
     # truncating back to the stream width is exact: subtraction mod 2^32
     # then mod 2^(8w) equals subtraction mod 2^(8w)
@@ -616,11 +612,7 @@ def _bitpack_enc_device(streams, params):
         (int(x.max()) if x.size else 0).bit_length(), 1
     )
     words = np.asarray(
-        ops.bitpack(
-            jnp.asarray(x.astype(np.uint32, copy=False)),
-            bits,
-            use_pallas=device_use_pallas(),
-        )
+        ops.bitpack(jnp.asarray(x.astype(np.uint32, copy=False)), bits)
     )
     packed = _packed_words_to_bytes(words, x.size, bits)
     h = HeaderWriter().u8(bits).u8(s.width).varint(x.size).done()
@@ -656,9 +648,7 @@ def _fused_enc_device(streams, params):
         raise ValueError(
             "fused_delta_bitpack: lossless precondition failed (delta too wide)"
         )
-    words = np.asarray(
-        ops.fused_delta_bitpack(xj, bits, use_pallas=device_use_pallas())
-    )
+    words = np.asarray(ops.fused_delta_bitpack(xj, bits))
     packed = _packed_words_to_bytes(words, x.size, bits).copy()
     # the kernel zero-pads the *input*, so the padding deltas (0 - x[-1]) can
     # smear garbage into the final partial byte; the host bitstream is zero
@@ -683,7 +673,7 @@ def _shuffle_planes(s: Stream) -> np.ndarray:
 
     raw = np.frombuffer(s.content_bytes(), dtype=np.uint8)
     mat = raw.reshape(-1, s.width)
-    return np.asarray(ops.byteshuffle(jnp.asarray(mat), use_pallas=device_use_pallas()))
+    return np.asarray(ops.byteshuffle(jnp.asarray(mat)))
 
 
 def _transpose_applies_device(streams, params):

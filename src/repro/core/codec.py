@@ -256,10 +256,13 @@ def run_encode_via(
     backend: str,
     streams: Sequence[Stream],
     params: Optional[dict] = None,
-) -> Tuple[List[Stream], bytes]:
+) -> Tuple[List[Stream], bytes, str]:
     """Encode through ``backend`` when an applicable impl exists, else host.
 
-    Backend output passes the same postconditions as the host encoder.
+    Returns ``(outs, header, encoded_by)``: ``encoded_by`` names the backend
+    that actually ran the node (``"host"`` when the node was routed back), so
+    callers can count what ran where.  Backend output passes the same
+    postconditions as the host encoder.
     """
     params = dict(params or {})
     if backend != HOST_BACKEND:
@@ -277,8 +280,8 @@ def run_encode_via(
                 )
             if not isinstance(header, (bytes, bytearray)):
                 raise AssertionError(f"backend {backend}:{spec.name}: header must be bytes")
-            return [o.validate() for o in outs], bytes(header)
-    return spec.run_encode(streams, params)
+            return [o.validate() for o in outs], bytes(header), backend
+    return (*spec.run_encode(streams, params), HOST_BACKEND)
 
 
 _loaded = False

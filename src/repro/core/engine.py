@@ -44,7 +44,7 @@ import io
 import os
 import threading
 import time
-from collections import OrderedDict, deque
+from collections import Counter, OrderedDict, deque
 from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor
 from concurrent.futures import wait as _futures_wait
 from dataclasses import dataclass, field
@@ -544,7 +544,9 @@ class _Executor:
     ``trace`` (optional) collects one ``(codec_name, input_bytes)`` pair per
     executed codec, in execution order — the raw material for the trainer's
     deterministic cost model (the counts are a pure function of plan + data,
-    unlike wall-clock timings).
+    unlike wall-clock timings).  ``routes`` (optional) counts executed nodes
+    by ``(encoded_by_backend, codec_name)``: a node the selected backend
+    declined counts under ``"host"``.
     """
 
     def __init__(
@@ -553,10 +555,12 @@ class _Executor:
         streams: Sequence[Stream],
         backend: str,
         trace: Optional[List[Tuple[str, int]]] = None,
+        routes: Optional[Counter] = None,
     ):
         self.resolved = resolved
         self.backend = backend
         self.trace = trace
+        self.routes = routes
         self.edges: List[Stream] = []
         self.consumed: List[bool] = []
         self.nodes: List[ResolvedNode] = []
@@ -582,7 +586,9 @@ class _Executor:
         ins = [self._consume(e) for e in rt_ins]
         if self.trace is not None:
             self.trace.append((name, sum(s.nbytes for s in ins)))
-        outs, header = run_encode_via(spec, self.backend, ins, params)
+        outs, header, by = run_encode_via(spec, self.backend, ins, params)
+        if self.routes is not None:
+            self.routes[(by, name)] += 1
         out_ids = [self._new_edge(o) for o in outs]
         self.nodes.append(ResolvedNode(spec.codec_id, tuple(rt_ins), len(outs), header))
         return out_ids
@@ -624,7 +630,7 @@ class _Executor:
         params = step.param_dict()
         s = self.edges[rt_ins[0]]  # peek: do not consume before we commit
         try:
-            outs, header = run_encode_via(spec, self.backend, [s], params)
+            outs, header, by = run_encode_via(spec, self.backend, [s], params)
         except ValueError:
             explicit = int(params.get("bits", 0))
             d_out = self._run_codec("delta", {}, rt_ins)
@@ -634,6 +640,8 @@ class _Executor:
         self._consume(rt_ins[0])
         if self.trace is not None:
             self.trace.append((FUSED_NAME, s.nbytes))
+        if self.routes is not None:
+            self.routes[(by, FUSED_NAME)] += 1
         out_ids = [self._new_edge(o) for o in outs]
         self.nodes.append(ResolvedNode(spec.codec_id, tuple(rt_ins), len(outs), header))
         return out_ids
@@ -647,6 +655,7 @@ def execute(
     fuse: Optional[bool] = None,
     scratch: Optional[ExecScratch] = None,
     trace: Optional[List[Tuple[str, int]]] = None,
+    routes: Optional[Counter] = None,
 ) -> bytes:
     """Phase 2: run a resolved program over concrete streams -> wire frame.
 
@@ -655,7 +664,8 @@ def execute(
     per-call coder-table caching; the chunked ``compress()`` path passes one
     shared scratch to every pool worker so read-only tables are built once.
     ``trace`` (a caller-owned list) collects ``(codec_name, input_bytes)`` per
-    executed step — see :class:`_Executor`.
+    executed step and ``routes`` (a caller-owned Counter) the backend that
+    encoded each node — see :class:`_Executor`.
     """
     streams = [s.validate() for s in _as_streams(inputs)]
     if len(streams) != resolved.n_inputs:
@@ -671,9 +681,9 @@ def execute(
     if fuse:
         resolved = fuse_resolved(resolved)
     if scratch is None:
-        return _Executor(resolved, streams, backend, trace).run()
+        return _Executor(resolved, streams, backend, trace, routes).run()
     with scratch.activate():
-        return _Executor(resolved, streams, backend, trace).run()
+        return _Executor(resolved, streams, backend, trace, routes).run()
 
 
 # ------------------------------------------------------------------ chunking
@@ -974,6 +984,9 @@ class CompressorSession(_SessionBase):
         # guarantee) and the failure recorded; a quarantined backend is
         # skipped outright.  None (the default) keeps errors fatal.
         self.failover = failover
+        # executed nodes by the backend that encoded them -> codec -> count
+        # ("host" holds nodes the session's backend declined or failed over)
+        self.stats["nodes"] = {}
 
     # ------------------------------------------------------------ one-shot
     def compress(
@@ -1029,22 +1042,31 @@ class CompressorSession(_SessionBase):
         fo = self.failover
         if backend != "host" and fo is not None and fo.quarantined(backend):
             backend = "host"
+        routes: Counter = Counter()
         try:
             out = execute(
-                resolved, streams, backend=backend, scratch=self.scratch, trace=trace
+                resolved, streams, backend=backend, scratch=self.scratch,
+                trace=trace, routes=routes,
             )
         except Exception as err:
             if backend == "host" or fo is None:
                 raise
             if trace is not None:
                 trace.clear()
+            routes.clear()
             out = execute(
-                resolved, streams, backend="host", scratch=self.scratch, trace=trace
+                resolved, streams, backend="host", scratch=self.scratch,
+                trace=trace, routes=routes,
             )
             fo.record_failure(backend, err)  # host succeeded: backend-specific
-            return out
-        if backend != "host" and fo is not None:
-            fo.record_success(backend)
+        else:
+            if backend != "host" and fo is not None:
+                fo.record_success(backend)
+        with self._stats_lock:
+            nodes = self.stats["nodes"]
+            for (by, name), k in routes.items():
+                per = nodes.setdefault(by, {})
+                per[name] = per.get(name, 0) + k
         return out
 
     def _compress_single(

@@ -14,7 +14,6 @@ Cross-pod wire bytes per step:
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import Any, Tuple
 
 import jax
@@ -52,27 +51,14 @@ def make_pod_dp_train_step(cfg, optimizer: Optimizer, mesh: Mesh, method: str):
     batch_spec = {"tokens": P("pod"), "labels": P("pod")}
     in_specs = (rep, rep, P("pod"), batch_spec)
     out_specs = (rep, rep, P("pod"), rep)
-    if hasattr(jax, "shard_map"):
-        step = partial(
-            jax.shard_map,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            axis_names={"pod"},
-            check_vma=False,
-        )(body)
-    else:  # older jax: same partial-manual mapping via the experimental API
-        from jax.experimental.shard_map import shard_map
-
-        step = shard_map(
-            body,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_rep=False,
-            auto=frozenset(mesh.axis_names) - {"pod"},
-        )
-    return step
+    return jax.shard_map(
+        body,
+        mesh=mesh,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        axis_names={"pod"},
+        check_vma=False,
+    )
 
 
 def make_ef_state_specs(params_sds, n_pods: int):

@@ -18,7 +18,7 @@ def _shuffle_kernel(x_ref, o_ref):
     o_ref[...] = x_ref[...].T
 
 
-def byteshuffle_pallas(x: jax.Array, *, interpret: bool = True) -> jax.Array:
+def byteshuffle_pallas(x: jax.Array, *, interpret: bool) -> jax.Array:
     """x: (n, w) uint8 with n % BLOCK == 0 -> (w, n) uint8."""
     n, w = x.shape
     assert n % BLOCK == 0, "caller pads to BLOCK multiple"
@@ -33,7 +33,7 @@ def byteshuffle_pallas(x: jax.Array, *, interpret: bool = True) -> jax.Array:
     )(x)
 
 
-def byteunshuffle_pallas(p: jax.Array, *, interpret: bool = True) -> jax.Array:
+def byteunshuffle_pallas(p: jax.Array, *, interpret: bool) -> jax.Array:
     """p: (w, n) uint8 planes -> (n, w) records (inverse)."""
     w, n = p.shape
     assert n % BLOCK == 0, "caller pads to BLOCK multiple"

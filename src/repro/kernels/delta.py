@@ -27,7 +27,7 @@ def _encode_kernel(x_ref, xprev_ref, o_ref):
     o_ref[...] = x - shifted
 
 
-def delta_encode_pallas(x: jax.Array, *, interpret: bool = True) -> jax.Array:
+def delta_encode_pallas(x: jax.Array, *, interpret: bool) -> jax.Array:
     n = x.shape[0]
     assert n % BLOCK == 0, "caller pads to BLOCK multiple"
     grid = (n // BLOCK,)
@@ -53,7 +53,7 @@ def _scan_carry_kernel(x_ref, carry_ref, o_ref):
     o_ref[...] = jnp.cumsum(x_ref[...], dtype=jnp.uint32) + carry_ref[0]
 
 
-def delta_decode_pallas(d: jax.Array, *, interpret: bool = True) -> jax.Array:
+def delta_decode_pallas(d: jax.Array, *, interpret: bool) -> jax.Array:
     n = d.shape[0]
     assert n % BLOCK == 0, "caller pads to BLOCK multiple"
     grid = (n // BLOCK,)

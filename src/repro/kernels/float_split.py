@@ -39,7 +39,7 @@ def _merge_kernel(exp_bits: int, man_bits: int):
 
 
 def float_split_pallas(
-    u: jax.Array, exp_bits: int, man_bits: int, *, interpret: bool = True
+    u: jax.Array, exp_bits: int, man_bits: int, *, interpret: bool
 ):
     n = u.shape[0]
     assert n % BLOCK == 0, "caller pads to BLOCK multiple"
@@ -66,7 +66,7 @@ def float_merge_pallas(
     exp_bits: int,
     man_bits: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
 ):
     n = sign.shape[0]
     assert n % BLOCK == 0, "caller pads to BLOCK multiple"

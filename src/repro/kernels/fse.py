@@ -1,14 +1,9 @@
-"""Pallas TPU kernels: tANS (FSE) interleaved-state encode scan + decode.
+"""Pallas TPU kernel: tANS (FSE) lane-parallel decode.
 
-State machine after the SCL FSE exemplar and the host coder
-(``repro.codecs.entropy``): encode walks each lane *backward*, carrying an
-int32 state in [0, 2*2^table_log); a lane of length r initializes its state
-at position r-1 and, for every earlier position, emits the low
-``nb0[s] - (X < thr[s])`` bits of ``X = state + total`` before stepping
-through the flattened encode table.  The kernel produces the per-position
-(value, nbits) planes plus final states; bit I/O composition (suffix-sum
-offsets + the scatter-add packer) is XLA glue in ops.py — placing values
-directly into the concatenated wire layout.
+Encode has no Pallas kernel: its backward state walk steps through the
+flattened encode table (up to 256 x 2^table_log entries), a gather that
+Mosaic lowers only within one vreg.  ``ref.fse_encode_lanes`` runs it as
+plain XLA on every backend (``ops.fse_encode``).
 
 Decode is the forward walk: emit ``dec_sym[state]``, retreat the bit cursor,
 refill a 32-bit window from the per-lane padded buffer (lane_refill gather
@@ -25,99 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 LANE_BLOCK = 256  # lanes per grid step
-
-
-def _encode_kernel(
-    lanesT_ref,
-    rem_ref,
-    nb0_ref,
-    thr_ref,
-    st0_ref,
-    norm_ref,
-    enc_ref,
-    val_ref,
-    nbs_ref,
-    state_ref,
-    *,
-    width,
-    total,
-    max_rem,
-):
-    rem = rem_ref[...].astype(jnp.int32)
-    nb0 = nb0_ref[...]
-    thr = thr_ref[...]
-    st0 = st0_ref[...]
-    norm = norm_ref[...]
-    enc = enc_ref[...]
-
-    def step(j, state):
-        i = max_rem - 1 - j
-        s = lanesT_ref[pl.ds(i, 1), :].reshape(-1).astype(jnp.int32)
-        emit = rem > i + 1
-        X = state + total
-        nb = jnp.take(nb0, s) - (X < jnp.take(thr, s)).astype(jnp.int32)
-        nbe = jnp.where(emit, nb, 0)
-        val = X.astype(jnp.uint32) & (
-            (jnp.uint32(1) << nbe.astype(jnp.uint32)) - jnp.uint32(1)
-        )
-        val_ref[pl.ds(i, 1), :] = val[None, :]
-        nbs_ref[pl.ds(i, 1), :] = nbe[None, :]
-        xprime = jnp.clip((X >> nb) - jnp.take(norm, s), 0, width - 1)
-        new_state = jnp.take(enc, s * width + xprime)
-        return jnp.where(
-            emit, new_state, jnp.where(rem == i + 1, jnp.take(st0, s), state)
-        )
-
-    state_ref[...] = jax.lax.fori_loop(
-        0, max_rem, step, jnp.zeros(rem.shape, jnp.int32)
-    )
-
-
-def fse_encode_pallas(
-    lanesT: jax.Array,
-    rem: jax.Array,
-    nb0: jax.Array,
-    thr: jax.Array,
-    st0: jax.Array,
-    norm: jax.Array,
-    enc_flat: jax.Array,
-    width: int,
-    total: int,
-    *,
-    interpret: bool = True,
-):
-    """(lanesT u8 (max_rem, n_lanes), rem i32, per-symbol tables i32[256],
-    enc_flat i32) -> (vals u32, nbits i32) planes + final lane states i32."""
-    max_rem, n = lanesT.shape
-    assert n % LANE_BLOCK == 0, "caller pads lanes to LANE_BLOCK multiple"
-    grid = (n // LANE_BLOCK,)
-    tab = lambda a: pl.BlockSpec(a.shape, lambda i: (0,))
-    return pl.pallas_call(
-        functools.partial(
-            _encode_kernel, width=width, total=total, max_rem=max_rem
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((max_rem, LANE_BLOCK), lambda i: (0, i)),
-            pl.BlockSpec((LANE_BLOCK,), lambda i: (i,)),
-            tab(nb0),
-            tab(thr),
-            tab(st0),
-            tab(norm),
-            tab(enc_flat),
-        ],
-        out_specs=[
-            pl.BlockSpec((max_rem, LANE_BLOCK), lambda i: (0, i)),
-            pl.BlockSpec((max_rem, LANE_BLOCK), lambda i: (0, i)),
-            pl.BlockSpec((LANE_BLOCK,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((max_rem, n), jnp.uint32),
-            jax.ShapeDtypeStruct((max_rem, n), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(lanesT, rem, nb0, thr, st0, norm, enc_flat)
 
 
 def _decode_kernel(
@@ -174,7 +76,7 @@ def fse_decode_pallas(
     dec_base: jax.Array,
     max_rem: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """(flat u8 concatenated per-lane padded buffers, lane_base i32 byte
     offsets, bitlen i32 bit lengths, state0 i32 final states, decode tables

@@ -3,8 +3,9 @@
 Encode on the device is table gathers plus bit packing: ``huffman_map``
 turns symbols into (canonical code, length) pairs, and the shared
 scatter-add packer (``ref.pack_bits`` / ops glue) places them at their
-cumsum bit offsets.  The map kernel here is the gather; packing stays in
-XLA (scatter-add has no Pallas win).
+cumsum bit offsets.  The map kernel here is the gather, restated as
+in-vreg lane gathers over a (2, 128) table layout; packing stays in XLA
+(scatter-add has no Pallas win).
 
 Decode is the lane-refill loop made device-resident: each lane gathers the
 five bytes straddling its cursor, stitches a 32-bit LSB-first window
@@ -21,41 +22,56 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-MAP_BLOCK = 2048  # symbols per grid step for the encode map
+LANES = 128
+MAP_ROWS = 512  # rows of 128 symbols per grid step of the encode map
+MAP_BLOCK = MAP_ROWS * LANES  # the map's padding multiple
 LANE_BLOCK = 256  # lanes per grid step for decode
+
+
+def _table_lookup(tab: jax.Array, x: jax.Array) -> jax.Array:
+    """``tab[x]`` for a (2, 128) table of 256 entries and (rows, 128) x:
+    two in-vreg lane gathers (one per table half) and a select."""
+    lo = jnp.take_along_axis(
+        jnp.broadcast_to(tab[0:1], x.shape), x & (LANES - 1), axis=1
+    )
+    hi = jnp.take_along_axis(
+        jnp.broadcast_to(tab[1:2], x.shape), x & (LANES - 1), axis=1
+    )
+    return jnp.where(x >= LANES, hi, lo)
 
 
 def _map_kernel(x_ref, codes_ref, lens_ref, code_ref, nbit_ref):
     xi = x_ref[...].astype(jnp.int32)
-    code_ref[...] = jnp.take(codes_ref[...].astype(jnp.uint32), xi)
-    nbit_ref[...] = jnp.take(lens_ref[...].astype(jnp.int32), xi)
+    code_ref[...] = _table_lookup(codes_ref[...], xi)
+    nbit_ref[...] = _table_lookup(lens_ref[...], xi)
 
 
 def huffman_map_pallas(
-    x: jax.Array, codes: jax.Array, lens: jax.Array, *, interpret: bool = True
+    x: jax.Array, codes: jax.Array, lens: jax.Array, *, interpret: bool
 ):
-    """(x u8, codes u32[256], lens i32[256]) -> (code u32, nbits i32) per sym."""
+    """(x u8, codes u32[256], lens i32[256]) -> (code u32, nbits i32) per sym.
+
+    Symbols go in as their lane-dense (rows, 128) view and the tables as
+    (2, 128): Mosaic lowers only 2-D gathers within a vreg, never a 1-D
+    ``jnp.take``."""
     n = x.shape[0]
     assert n % MAP_BLOCK == 0, "caller pads symbols to MAP_BLOCK multiple"
+    rows = n // LANES
     grid = (n // MAP_BLOCK,)
-    return pl.pallas_call(
+    block = pl.BlockSpec((MAP_ROWS, LANES), lambda i: (i, 0))
+    table = pl.BlockSpec((2, LANES), lambda i: (0, 0))
+    code, nbit = pl.pallas_call(
         _map_kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((MAP_BLOCK,), lambda i: (i,)),
-            pl.BlockSpec(codes.shape, lambda i: (0,)),  # whole code table
-            pl.BlockSpec(lens.shape, lambda i: (0,)),  # whole length table
-        ],
-        out_specs=[
-            pl.BlockSpec((MAP_BLOCK,), lambda i: (i,)),
-            pl.BlockSpec((MAP_BLOCK,), lambda i: (i,)),
-        ],
+        in_specs=[block, table, table],
+        out_specs=[block, block],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.uint32),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((rows, LANES), jnp.uint32),
+            jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
         ],
         interpret=interpret,
-    )(x, codes, lens)
+    )(x.reshape(rows, LANES), codes.reshape(2, LANES), lens.reshape(2, LANES))
+    return code.reshape(n), nbit.reshape(n)
 
 
 def _decode_kernel(pos_ref, buf_ref, sym_ref, len_ref, o_ref, *, max_rem):
@@ -88,7 +104,7 @@ def huffman_decode_pallas(
     lut_len: jax.Array,
     max_rem: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """(buf u8 padded >= 5 bytes past every cursor, pos i32 lane bit starts,
     lut_sym/lut_len 2^15 LUTs) -> (max_rem, n_lanes) u8 symbols."""

@@ -37,7 +37,7 @@ def _refill_kernel(pos_ref, buf_ref, o_ref):
 
 
 def lane_refill_pallas(
-    buf: jax.Array, bitpos: jax.Array, *, interpret: bool = True
+    buf: jax.Array, bitpos: jax.Array, *, interpret: bool
 ) -> jax.Array:
     """(buf u8, padded past every cursor by >= 5 bytes; bitpos i32) -> u32."""
     n = bitpos.shape[0]

@@ -1,22 +1,26 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Handles capacity padding (XLA static shapes — DESIGN.md §2.1), backend
-selection (`use_pallas=False` falls back to the jnp oracle in ref.py), and
-the lossless-precondition checks for the fused kernel.
+Handles capacity padding (XLA static shapes — DESIGN.md §2.1), kernel
+selection and the lossless-precondition checks for the fused kernel.
 
-On this CPU container Pallas executes in interpret mode; on TPU the same
-calls compile to Mosaic.  `interpret` is resolved from the backend.
+Kernel selection is ``repro.device.on_tpu()``, decided here and nowhere
+else: ``use_pallas=None`` (what the device backend passes) means the Pallas
+kernel compiled by Mosaic on a TPU and the jnp oracle in ref.py elsewhere.
+An explicit ``use_pallas=True`` off the TPU runs the kernel in interpret
+mode (the kernel tests); on a TPU a kernel never runs in interpret mode.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+from repro.device import on_tpu
 
 from . import ref
-from .bitpack import BLOCK_WORDS, bitpack_pallas, bitunpack_pallas
+from .bitpack import BLOCK_VALS, BLOCK_WORDS, bitpack_pallas, bitunpack_pallas
 from .byteshuffle import BLOCK as SHUF_BLOCK, byteshuffle_pallas, byteunshuffle_pallas
 from .delta import BLOCK as DELTA_BLOCK, delta_decode_pallas, delta_encode_pallas
 from .float_split import BLOCK as FS_BLOCK, float_merge_pallas, float_split_pallas
@@ -24,11 +28,16 @@ from .fused_delta_bitpack import (
     fused_delta_bitpack_decode_pallas,
     fused_delta_bitpack_pallas,
 )
-from .histogram import BLOCK as HIST_BLOCK, histogram_pallas
+
+Flag = Optional[bool]  # use_pallas: None = the device decision
+
+
+def _pallas(use_pallas: Flag) -> bool:
+    return on_tpu() if use_pallas is None else use_pallas
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return not on_tpu()
 
 
 def _pad_to(x: jax.Array, multiple: int) -> jax.Array:
@@ -41,11 +50,11 @@ def _pad_to(x: jax.Array, multiple: int) -> jax.Array:
 
 # --------------------------------------------------------------------- delta
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
-def delta_encode(x: jax.Array, *, use_pallas: bool = True) -> jax.Array:
+def delta_encode(x: jax.Array, *, use_pallas: Flag = None) -> jax.Array:
     x = x.astype(jnp.uint32)
     if x.shape[0] == 0:
         return x
-    if not use_pallas:
+    if not _pallas(use_pallas):
         return ref.delta_encode(x)
     n = x.shape[0]
     out = delta_encode_pallas(_pad_to(x, DELTA_BLOCK), interpret=_interpret())
@@ -53,11 +62,11 @@ def delta_encode(x: jax.Array, *, use_pallas: bool = True) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
-def delta_decode(d: jax.Array, *, use_pallas: bool = True) -> jax.Array:
+def delta_decode(d: jax.Array, *, use_pallas: Flag = None) -> jax.Array:
     d = d.astype(jnp.uint32)
     if d.shape[0] == 0:
         return d
-    if not use_pallas:
+    if not _pallas(use_pallas):
         return ref.delta_decode(d)
     n = d.shape[0]
     out = delta_decode_pallas(_pad_to(d, DELTA_BLOCK), interpret=_interpret())
@@ -66,11 +75,11 @@ def delta_decode(d: jax.Array, *, use_pallas: bool = True) -> jax.Array:
 
 # --------------------------------------------------------------- byteshuffle
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
-def byteshuffle(x: jax.Array, *, use_pallas: bool = True) -> jax.Array:
+def byteshuffle(x: jax.Array, *, use_pallas: Flag = None) -> jax.Array:
     """(n, w) uint8 -> (w, n)."""
     if x.shape[0] == 0:
         return x.T
-    if not use_pallas:
+    if not _pallas(use_pallas):
         return ref.byteshuffle_encode(x)
     n = x.shape[0]
     out = byteshuffle_pallas(_pad_to(x, SHUF_BLOCK), interpret=_interpret())
@@ -78,11 +87,11 @@ def byteshuffle(x: jax.Array, *, use_pallas: bool = True) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
-def byteunshuffle(p: jax.Array, *, use_pallas: bool = True) -> jax.Array:
+def byteunshuffle(p: jax.Array, *, use_pallas: Flag = None) -> jax.Array:
     """(w, n) uint8 -> (n, w)."""
     if p.shape[1] == 0:
         return p.T
-    if not use_pallas:
+    if not _pallas(use_pallas):
         return ref.byteshuffle_decode(p)
     w, n = p.shape
     pad = (-n) % SHUF_BLOCK
@@ -94,7 +103,7 @@ def byteunshuffle(p: jax.Array, *, use_pallas: bool = True) -> jax.Array:
 
 # ------------------------------------------------------------------- bitpack
 @functools.partial(jax.jit, static_argnames=("bits", "use_pallas"))
-def bitpack(x: jax.Array, bits: int, *, use_pallas: bool = True) -> jax.Array:
+def bitpack(x: jax.Array, bits: int, *, use_pallas: Flag = None) -> jax.Array:
     """Returns packed words for ceil(n/per) values; caller tracks n."""
     x = x.astype(jnp.uint32)
     per = 32 // bits
@@ -102,44 +111,33 @@ def bitpack(x: jax.Array, bits: int, *, use_pallas: bool = True) -> jax.Array:
     n_words = -(-n // per)
     if n == 0:
         return jnp.zeros((0,), jnp.uint32)
-    if not use_pallas:
+    if not _pallas(use_pallas):
         return ref.bitpack_encode(_pad_to(x, per), bits)[:n_words]
-    out = bitpack_pallas(_pad_to(x, BLOCK_WORDS * per), bits, interpret=_interpret())
+    out = bitpack_pallas(_pad_to(x, BLOCK_VALS), bits, interpret=_interpret())
     return out[:n_words]
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "n", "use_pallas"))
-def bitunpack(w: jax.Array, bits: int, n: int, *, use_pallas: bool = True) -> jax.Array:
+def bitunpack(
+    w: jax.Array, bits: int, n: int, *, use_pallas: Flag = None
+) -> jax.Array:
     if w.shape[0] == 0:
         return jnp.zeros((n,), jnp.uint32)
-    if not use_pallas:
+    if not _pallas(use_pallas):
         return ref.bitpack_decode(w, bits)[:n]
     out = bitunpack_pallas(_pad_to(w, BLOCK_WORDS), bits, interpret=_interpret())
     return out[:n]
 
 
-# ----------------------------------------------------------------- histogram
-@functools.partial(jax.jit, static_argnames=("use_pallas",))
-def histogram(x: jax.Array, *, use_pallas: bool = True) -> jax.Array:
-    """256-bin counts of uint8 symbols.  Padding adds to bin 0; corrected."""
-    x = x.astype(jnp.uint8)
-    n = x.shape[0]
-    if n == 0:
-        return jnp.zeros((256,), jnp.int32)
-    if not use_pallas:
-        return ref.histogram(x)
-    pad = (-n) % HIST_BLOCK
-    counts = histogram_pallas(_pad_to(x, HIST_BLOCK), interpret=_interpret())
-    return counts.at[0].add(-pad)
-
-
 # --------------------------------------------------------------- float_split
 @functools.partial(jax.jit, static_argnames=("exp_bits", "man_bits", "use_pallas"))
-def float_split(u: jax.Array, exp_bits: int, man_bits: int, *, use_pallas: bool = True):
+def float_split(
+    u: jax.Array, exp_bits: int, man_bits: int, *, use_pallas: Flag = None
+):
     u = u.astype(jnp.uint32)
     if u.shape[0] == 0:
         return ref.float_split_encode(u, exp_bits, man_bits)
-    if not use_pallas:
+    if not _pallas(use_pallas):
         return ref.float_split_encode(u, exp_bits, man_bits)
     n = u.shape[0]
     sign, exp, man = float_split_pallas(
@@ -149,10 +147,12 @@ def float_split(u: jax.Array, exp_bits: int, man_bits: int, *, use_pallas: bool 
 
 
 @functools.partial(jax.jit, static_argnames=("exp_bits", "man_bits", "use_pallas"))
-def float_merge(sign, exp, man, exp_bits: int, man_bits: int, *, use_pallas: bool = True):
+def float_merge(
+    sign, exp, man, exp_bits: int, man_bits: int, *, use_pallas: Flag = None
+):
     if sign.shape[0] == 0:
         return ref.float_split_decode(sign, exp, man, exp_bits, man_bits)
-    if not use_pallas:
+    if not _pallas(use_pallas):
         return ref.float_split_decode(sign, exp, man, exp_bits, man_bits)
     n = sign.shape[0]
     out = float_merge_pallas(
@@ -174,17 +174,17 @@ def fused_delta_bitpack_fits(x: jax.Array, bits: int) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "use_pallas"))
-def fused_delta_bitpack(x: jax.Array, bits: int, *, use_pallas: bool = True):
+def fused_delta_bitpack(x: jax.Array, bits: int, *, use_pallas: Flag = None):
     x = x.astype(jnp.uint32)
     per = 32 // bits
     n = x.shape[0]
     n_words = -(-n // per)
     if n == 0:
         return jnp.zeros((0,), jnp.uint32)
-    if not use_pallas:
+    if not _pallas(use_pallas):
         return ref.fused_delta_bitpack_encode(_pad_to(x, per), bits)[:n_words]
     # pad by REPEATING the last value so padded deltas are 0 (still fit)
-    pad = (-n) % (BLOCK_WORDS * per)
+    pad = (-n) % BLOCK_VALS
     if pad and n:
         x = jnp.concatenate([x, jnp.broadcast_to(x[-1], (pad,))])
     elif pad:
@@ -194,10 +194,12 @@ def fused_delta_bitpack(x: jax.Array, bits: int, *, use_pallas: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "n", "use_pallas"))
-def fused_delta_bitpack_decode(w: jax.Array, bits: int, n: int, *, use_pallas: bool = True):
+def fused_delta_bitpack_decode(
+    w: jax.Array, bits: int, n: int, *, use_pallas: Flag = None
+):
     if w.shape[0] == 0:
         return jnp.zeros((n,), jnp.uint32)
-    if not use_pallas:
+    if not _pallas(use_pallas):
         return ref.fused_delta_bitpack_decode(w, bits)[:n]
     out = fused_delta_bitpack_decode_pallas(
         _pad_to(w, BLOCK_WORDS), bits, interpret=_interpret()
@@ -210,9 +212,8 @@ def fused_delta_bitpack_decode(w: jax.Array, bits: int, n: int, *, use_pallas: b
 def histogram_exact(x: jax.Array) -> jax.Array:
     """256-bin counts with integer accumulation — exact at any stream size.
 
-    The MXU ``histogram`` kernel is f32 and only exact below 2^24 per bin;
-    entropy-coder table construction needs exact counts, so the device twins
-    use this (scatter-add on both backends — no Pallas variant needed)."""
+    Entropy-coder table construction needs exact counts, so the device twins
+    use this (scatter-add, plain XLA on every backend)."""
     return ref.histogram_exact(x.astype(jnp.uint8))
 
 
@@ -225,7 +226,9 @@ def pack_bits(vals: jax.Array, offs: jax.Array, total_bytes: int) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
-def huffman_map(x: jax.Array, codes: jax.Array, lens: jax.Array, *, use_pallas: bool = True):
+def huffman_map(
+    x: jax.Array, codes: jax.Array, lens: jax.Array, *, use_pallas: Flag = None
+):
     """Symbols -> (canonical code u32, nbits i32, exclusive bit offs i32[n+1]).
 
     ``offs[-1]`` is the total bit count; the cumsum stays int32, so callers
@@ -239,7 +242,7 @@ def huffman_map(x: jax.Array, codes: jax.Array, lens: jax.Array, *, use_pallas: 
     if n == 0:
         z = jnp.zeros((0,), jnp.uint32)
         return z, z.astype(jnp.int32), jnp.zeros((1,), jnp.int32)
-    if use_pallas:
+    if _pallas(use_pallas):
         code, nb = huffman_map_pallas(
             _pad_to(x, MAP_BLOCK), codes, lens, interpret=_interpret()
         )
@@ -260,7 +263,7 @@ def huffman_decode(
     lut_len: jax.Array,
     max_rem: int,
     *,
-    use_pallas: bool = True,
+    use_pallas: Flag = None,
 ):
     """Lane-parallel Huffman decode -> (max_rem, n_lanes) u8 symbols.
 
@@ -272,7 +275,7 @@ def huffman_decode(
     n = pos.shape[0]
     if n == 0 or max_rem == 0:
         return jnp.zeros((max_rem, n), jnp.uint8)
-    if not use_pallas:
+    if not _pallas(use_pallas):
         return ref.huffman_decode_lanes(buf, pos, lut_sym, lut_len, max_rem)
     out = huffman_decode_pallas(
         buf,
@@ -286,7 +289,7 @@ def huffman_decode(
 
 
 # -------------------------------------------------------------- entropy: fse
-@functools.partial(jax.jit, static_argnames=("width", "total", "use_pallas"))
+@functools.partial(jax.jit, static_argnames=("width", "total"))
 def fse_encode(
     lanesT: jax.Array,
     rem: jax.Array,
@@ -297,8 +300,6 @@ def fse_encode(
     enc_flat: jax.Array,
     width: int,
     total: int,
-    *,
-    use_pallas: bool = True,
 ):
     """tANS backward scan + wire-layout bit offsets.
 
@@ -306,34 +307,10 @@ def fse_encode(
     per-lane bit lengths, lane byte offsets i32[n+1]).  The offsets place
     every emission directly into the *concatenated* per-lane bitstream
     layout the host encoder produces, so one ``pack_bits`` call yields the
-    final wire bytes."""
-    from .fse import LANE_BLOCK, fse_encode_pallas
-
-    max_rem, n = lanesT.shape
-    rem = rem.astype(jnp.int32)
-    if use_pallas:
-        pad = (-n) % LANE_BLOCK
-        if pad:
-            lanesT = jnp.concatenate(
-                [lanesT, jnp.zeros((max_rem, pad), lanesT.dtype)], axis=1
-            )
-        vals, nbs, state = fse_encode_pallas(
-            lanesT,
-            _pad_to(rem, LANE_BLOCK),
-            nb0.astype(jnp.int32),
-            thr.astype(jnp.int32),
-            st0.astype(jnp.int32),
-            norm.astype(jnp.int32),
-            enc_flat.astype(jnp.int32),
-            width,
-            total,
-            interpret=_interpret(),
-        )
-        vals, nbs, state = vals[:, :n], nbs[:, :n], state[:n]
-    else:
-        vals, nbs, state = ref.fse_encode_lanes(
-            lanesT, rem, nb0, thr, st0, norm, enc_flat, width, total
-        )
+    final wire bytes.  Plain XLA on every backend (see kernels/fse.py)."""
+    vals, nbs, state = ref.fse_encode_lanes(
+        lanesT, rem.astype(jnp.int32), nb0, thr, st0, norm, enc_flat, width, total
+    )
     bitpos = jnp.sum(nbs, axis=0, dtype=jnp.int32)
     # emission order is decreasing position i, so the offset of emission i
     # within its lane is the suffix sum of later positions' bit counts
@@ -358,7 +335,7 @@ def fse_decode(
     dec_base: jax.Array,
     max_rem: int,
     *,
-    use_pallas: bool = True,
+    use_pallas: Flag = None,
 ):
     """Lane-parallel tANS decode -> (max_rem, n_lanes) u8 symbols."""
     from .fse import LANE_BLOCK, fse_decode_pallas
@@ -367,7 +344,7 @@ def fse_decode(
     n = bitlen.shape[0]
     if n == 0 or max_rem == 0:
         return jnp.zeros((max_rem, n), jnp.uint8)
-    if not use_pallas:
+    if not _pallas(use_pallas):
         return ref.fse_decode_lanes(
             flat, lane_base, bitlen, state0, dec_sym, dec_nb, dec_base, max_rem
         )
@@ -387,7 +364,7 @@ def fse_decode(
 
 # --------------------------------------------------------------- lane refill
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
-def lane_refill(buf: jax.Array, bitpos: jax.Array, *, use_pallas: bool = True):
+def lane_refill(buf: jax.Array, bitpos: jax.Array, *, use_pallas: Flag = None):
     """Entropy-lane window refill: next 32 bits per lane bit-cursor, u32.
 
     The device-side building block of the entropy decoders' gather refill
@@ -401,7 +378,7 @@ def lane_refill(buf: jax.Array, bitpos: jax.Array, *, use_pallas: bool = True):
     if n == 0:
         return jnp.zeros((0,), jnp.uint32)
     buf = buf.astype(jnp.uint8)
-    if not use_pallas:
+    if not _pallas(use_pallas):
         return ref.lane_refill(buf, bitpos)
     pos = _pad_to(bitpos.astype(jnp.int32), REFILL_BLOCK)
     out = lane_refill_pallas(buf, pos, interpret=_interpret())

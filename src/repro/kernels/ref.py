@@ -49,17 +49,6 @@ def bitpack_decode(w: jax.Array, bits: int) -> jax.Array:
     return ((w[:, None] >> shifts[None, :]) & mask).reshape(-1)
 
 
-# ----------------------------------------------------------------- histogram
-def histogram(x: jax.Array) -> jax.Array:
-    """256-bin histogram of uint8 symbols -> int32 counts."""
-    one_hot = (x[:, None] == jnp.arange(256, dtype=x.dtype)[None, :]).astype(
-        jnp.float32
-    )
-    # MXU form: ones-vector contraction (see DESIGN.md §2.5)
-    counts = jnp.dot(jnp.ones((x.shape[0],), jnp.float32), one_hot)
-    return counts.astype(jnp.int32)
-
-
 # --------------------------------------------------------------- float_split
 def float_split_encode(u: jax.Array, exp_bits: int, man_bits: int):
     """uint bit patterns -> (sign u8, exponent u8/u16, mantissa u32)."""
@@ -94,11 +83,8 @@ def fused_delta_bitpack_decode(w: jax.Array, bits: int) -> jax.Array:
 
 # ------------------------------------------------------------- exact histogram
 def histogram_exact(x: jax.Array) -> jax.Array:
-    """256-bin histogram with integer accumulation — exact at any count.
-
-    The MXU ``histogram`` kernel accumulates in f32 (exact only while every
-    bin stays below 2^24); entropy-coder *table construction* needs exact
-    counts at any stream size, so the device twins use this scatter-add."""
+    """256-bin histogram with integer accumulation — exact at any count
+    (entropy-coder table construction needs exact counts)."""
     return jnp.bincount(x.astype(jnp.int32), length=256).astype(jnp.int32)
 
 

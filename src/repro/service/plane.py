@@ -179,6 +179,18 @@ class _Worker:
 
 
 # -------------------------------------------------------------------- plane
+def check_device_workers(workers: int, backend: Optional[str]) -> None:
+    """Refuse a plane that would fork several processes onto one chip.
+
+    A chip belongs to one process: every forked worker gets ``backend``, and
+    a second worker that touches the device fails or hangs."""
+    if backend == "device" and workers > 1:
+        raise ValueError(
+            f"backend='device' needs one process per chip: got workers={workers};"
+            " use workers=1 or the threaded server"
+        )
+
+
 class ServicePlane:
     """Supervisor for a pre-forked pool of session-worker processes."""
 
@@ -213,6 +225,7 @@ class ServicePlane:
             raise ValueError("pass exactly one of socket_path= or host=")
         if workers < 1:
             raise ValueError("workers must be >= 1")
+        check_device_workers(workers, backend)
         self.registry = registry if registry is not None else PlanRegistry()
         self.workers = workers
         self.max_clients = max_clients

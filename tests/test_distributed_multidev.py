@@ -18,23 +18,6 @@ def run_with_devices(n_devices: int, body: str) -> str:
         f"""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n_devices}"
-        import jax as _jax
-        if not hasattr(_jax, "shard_map"):
-            # older jax: adapt the new jax.shard_map API to the experimental one
-            from jax.experimental.shard_map import shard_map as _esm
-
-            def _shard_map(f=None, *, mesh, in_specs, out_specs,
-                           axis_names=None, check_vma=True, **_kw):
-                auto = (
-                    frozenset(getattr(mesh, "axis_names", ())) - set(axis_names)
-                    if axis_names else frozenset()
-                )
-                def _wrap(fn):
-                    return _esm(fn, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs, check_rep=False, auto=auto)
-                return _wrap(f) if f is not None else _wrap
-
-            _jax.shard_map = _shard_map
         """
     ) + textwrap.dedent(body)
     env = dict(os.environ, PYTHONPATH=str(REPO / "src"))

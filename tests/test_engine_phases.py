@@ -411,3 +411,51 @@ def test_deserialize_legacy_blob_defaults():
     assert "format_version" not in meta and "level" not in meta
     c = Compressor.deserialize(blob)
     assert c.format_version == CURRENT_FORMAT_VERSION and c.level == 5
+
+
+# ------------------------------------------------- which backend ran a node
+def test_session_counts_nodes_by_encoding_backend():
+    """``stats["nodes"]`` records which backend encoded every executed node:
+    device twins count under "device", a codec with no twin under "host"."""
+    from repro.core import CompressorSession
+
+    x = sorted_u32(5000)
+    plan = pipeline("delta", "transpose", "zlib_backend")
+    with CompressorSession(plan, backend="device") as dev:
+        frame = dev.compress(x)
+        dev.compress(x, chunk_bytes=4000)  # 5 chunks, same routing each
+    assert frame == compress(plan, x, backend="host")
+    assert dev.stats["nodes"] == {
+        "device": {"delta": 6, "transpose": 6},
+        "host": {"zlib_backend": 6},
+    }
+    with CompressorSession(pipeline("delta", "bitpack"), backend="device") as s:
+        s.compress(x)
+    assert s.stats["nodes"] == {"device": {"fused_delta_bitpack": 1}}
+    with CompressorSession(plan) as host:
+        host.compress(x)
+    assert host.stats["nodes"] == {
+        "host": {"delta": 1, "transpose": 1, "zlib_backend": 1}
+    }
+
+
+def test_session_counts_failed_over_nodes_on_host():
+    """A chunk whose device kernel faults re-runs on the host; its nodes
+    count where they actually ran."""
+    from repro.core import CompressorSession
+    from repro.reliability import BackendHealth, FaultPlan
+
+    x = sorted_u32(5000)
+    plan = pipeline("delta", "transpose")
+    health = BackendHealth(threshold=2)  # one fault does not quarantine
+    sess = CompressorSession(plan, backend="device", failover=health)
+    with FaultPlan().at("device.encode.device.transpose", times=1).arm():
+        assert sess.compress(x) == compress(plan, x, backend="host")
+    assert sess.stats["nodes"] == {"host": {"delta": 1, "transpose": 1}}
+    assert health.stats()["device"]["failures"] == 1
+    sess.compress(x)
+    assert sess.stats["nodes"] == {
+        "host": {"delta": 1, "transpose": 1},
+        "device": {"delta": 1, "transpose": 1},
+    }
+    sess.close()

@@ -330,3 +330,27 @@ def test_connection_lost_is_hard_error_without_budget(tmp_path):
     finally:
         lst.close()
         t.join(10)
+
+
+def test_device_backend_refuses_several_worker_processes(tmp_path):
+    """A chip belongs to one process: a plane that would fork two workers
+    onto it is refused before it binds or forks anything, by the library and
+    by ``repro serve``."""
+    from repro.cli import main
+
+    sock = tmp_path / "dev.sock"
+    with pytest.raises(ValueError, match="one process per chip"):
+        ServicePlane(
+            _registry(), socket_path=str(sock), workers=2, backend="device"
+        )
+    assert not sock.exists()
+    with pytest.raises(SystemExit, match="one process per chip"):
+        main(["serve", "--socket", str(sock), "--profile", "generic",
+              "--workers", "2", "--backend", "device"])
+    assert not sock.exists()
+    # one worker on the device, or many on the host, stay allowed
+    from repro.service.plane import check_device_workers
+
+    check_device_workers(1, "device")
+    check_device_workers(4, "host")
+    check_device_workers(4, None)
