@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.core.codec import register_backend_codec
 from repro.core.message import Stream, SType
+from repro.device import to_device, to_host
 
 from ._util import HeaderWriter, device_available, numeric_stream
 from .entropy import (
@@ -66,26 +67,24 @@ def _huffman_applies_device(streams, params):
 
 
 def _huffman_enc_device(streams, params):
-    import jax.numpy as jnp
-
     from repro.kernels import ops
 
     x = _as_u8(streams[0], "huffman")
     n = x.size
-    xj = jnp.asarray(x)
-    counts = np.asarray(ops.histogram_exact(xj)).astype(np.int64)
+    xj = to_device(x)
+    counts = to_host(ops.histogram_exact(xj)).astype(np.int64)
     lens = _huffman_code_lengths(counts)
     codes = _huffman_codes_cached(lens)
     code, _nb, offs = ops.huffman_map(
-        xj, jnp.asarray(codes), jnp.asarray(lens.astype(np.int32))
+        xj, to_device(codes), to_device(lens.astype(np.int32))
     )
-    total = int(offs[-1])
+    total = int(to_host(offs[-1]))
     total_bytes = (total + 7) >> 3
-    packed = np.asarray(
+    packed = to_host(
         ops.pack_bits(code, offs[:-1], _cap_bucket(total_bytes))
     )[:total_bytes]
     block = 1 << BLOCK_LOG
-    block_offs = np.asarray(offs[: n : block]).astype(np.uint64)
+    block_offs = to_host(offs[: n : block]).astype(np.uint64)
     h = HeaderWriter().varint(n).u8(BLOCK_LOG).u8(int(streams[0].stype))
     nib = (lens[0::2] | (lens[1::2] << 4)).astype(np.uint8)
     h.bytes_(nib.tobytes())
@@ -106,16 +105,14 @@ def _fse_applies_device(streams, params):
 
 
 def _fse_enc_device(streams, params):
-    import jax.numpy as jnp
-
     from repro.kernels import ops
 
     x = _as_u8(streams[0], "fse")
     n = x.size
     table_log = int(params.get("table_log", 11))
     stype_tag = int(streams[0].stype)
-    xj = jnp.asarray(x)
-    counts = np.asarray(ops.histogram_exact(xj)).astype(np.int64)
+    xj = to_device(x)
+    counts = to_host(ops.histogram_exact(xj)).astype(np.int64)
     norm = _normalize_counts(counts, table_log)
     _ds, _dn, _db, enc_table, nb0t, thrt, st0t = _fse_tables_cached(norm, table_log)
     total = 1 << table_log
@@ -130,25 +127,25 @@ def _fse_enc_device(streams, params):
         n - np.arange(n_blocks, dtype=np.int64) * block, block
     ).astype(np.int32)
     vals, goffs, state, bitpos, byte_off = ops.fse_encode(
-        jnp.asarray(lanesT),
-        jnp.asarray(rem),
-        jnp.asarray(nb0t.astype(np.int32)),
-        jnp.asarray(thrt.astype(np.int32)),
-        jnp.asarray(st0t.astype(np.int32)),
-        jnp.asarray(norm.astype(np.int32)),
-        jnp.asarray(enc_table.reshape(-1)),
+        to_device(lanesT),
+        to_device(rem),
+        to_device(nb0t.astype(np.int32)),
+        to_device(thrt.astype(np.int32)),
+        to_device(st0t.astype(np.int32)),
+        to_device(norm.astype(np.int32)),
+        to_device(enc_table.reshape(-1)),
         width,
         total,
     )
-    total_bytes = int(byte_off[-1])
-    stream_out = np.asarray(
+    total_bytes = int(to_host(byte_off[-1]))
+    stream_out = to_host(
         ops.pack_bits(
             vals.reshape(-1), goffs.reshape(-1), _cap_bucket(total_bytes)
         )
     )[:total_bytes]
     meta = np.empty(n_blocks * 2, dtype=np.uint32)
-    meta[0::2] = np.asarray(bitpos).astype(np.uint32)
-    meta[1::2] = np.asarray(state).astype(np.uint32)
+    meta[0::2] = to_host(bitpos).astype(np.uint32)
+    meta[1::2] = to_host(state).astype(np.uint32)
 
     h = HeaderWriter().varint(n).u8(FSE_BLOCK_LOG).u8(table_log).u8(stype_tag)
     nz = np.nonzero(norm)[0]

@@ -25,6 +25,7 @@ from repro.core.codec import (
     register_codec,
 )
 from repro.core.message import Stream, SType
+from repro.device import to_device, to_host
 
 from ._util import (
     HeaderReader,
@@ -143,20 +144,18 @@ def _float_split_applies_device(streams, params):
 
 
 def _float_split_enc_device(streams, params):
-    import jax.numpy as jnp
-
     from repro.kernels import ops
 
     s = streams[0]
     fmt = 2
     _width, exp_bits, man_bits = FORMATS[fmt]
     u = s.data.view(np.uint32)
-    sign, exp, man = ops.float_split(jnp.asarray(u), exp_bits, man_bits)
+    sign, exp, man = ops.float_split(to_device(u), exp_bits, man_bits)
     h = HeaderWriter().u8(fmt).varint(u.size).done()
     return [
-        Stream(_pack_sign_bits(np.asarray(sign, np.uint8)), SType.SERIAL, 1),
-        numeric_stream(np.asarray(exp).astype(_EXP_DTYPE[fmt], copy=False)),
-        numeric_stream(np.asarray(man).astype(_MAN_DTYPE[fmt], copy=False)),
+        Stream(_pack_sign_bits(to_host(sign).astype(np.uint8)), SType.SERIAL, 1),
+        numeric_stream(to_host(exp).astype(_EXP_DTYPE[fmt], copy=False)),
+        numeric_stream(to_host(man).astype(_MAN_DTYPE[fmt], copy=False)),
     ], h
 
 

@@ -27,6 +27,7 @@ from repro.core.codec import (
     register_codec,
 )
 from repro.core.message import Stream, SType, from_wire
+from repro.device import to_device, to_host
 
 from ._util import (
     UNSIGNED,
@@ -561,15 +562,11 @@ def _delta_applies_device(streams, params):
 
 
 def _delta_enc_device(streams, params):
-    import jax.numpy as jnp
-
     from repro.kernels import ops
 
     s = streams[0]
     x = s.data.view(UNSIGNED[s.width])
-    d32 = np.asarray(
-        ops.delta_encode(jnp.asarray(x.astype(np.uint32, copy=False)))
-    )
+    d32 = to_host(ops.delta_encode(to_device(x.astype(np.uint32, copy=False))))
     # truncating back to the stream width is exact: subtraction mod 2^32
     # then mod 2^(8w) equals subtraction mod 2^(8w)
     return [numeric_stream(d32.astype(UNSIGNED[s.width], copy=False))], b""
@@ -602,8 +599,6 @@ def _packed_words_to_bytes(words: np.ndarray, n: int, bits: int) -> np.ndarray:
 
 
 def _bitpack_enc_device(streams, params):
-    import jax.numpy as jnp
-
     from repro.kernels import ops
 
     s = streams[0]
@@ -611,9 +606,7 @@ def _bitpack_enc_device(streams, params):
     bits = params.get("_device_bits") or int(params.get("bits", 0)) or max(
         (int(x.max()) if x.size else 0).bit_length(), 1
     )
-    words = np.asarray(
-        ops.bitpack(jnp.asarray(x.astype(np.uint32, copy=False)), bits)
-    )
+    words = to_host(ops.bitpack(to_device(x.astype(np.uint32, copy=False)), bits))
     packed = _packed_words_to_bytes(words, x.size, bits)
     h = HeaderWriter().u8(bits).u8(s.width).varint(x.size).done()
     return [Stream(packed, SType.SERIAL, 1)], h
@@ -640,15 +633,15 @@ def _fused_enc_device(streams, params):
     if s.stype != SType.NUMERIC or s.width not in (1, 2, 4):
         raise ValueError("fused_delta_bitpack: numeric(1/2/4) streams only")
     x = s.data.view(UNSIGNED[s.width]).astype(np.uint32, copy=False)
-    xj = jnp.asarray(x)
+    xj = to_device(x)
     # precondition check stays on device — the host never touches the deltas
-    maxd = int(jnp.max(ref.delta_encode(xj))) if x.size else 0
+    maxd = int(to_host(jnp.max(ref.delta_encode(xj)))) if x.size else 0
     bits = _bits_for_need(max(maxd.bit_length(), 1), int(params.get("bits", 0)))
     if bits is None:
         raise ValueError(
             "fused_delta_bitpack: lossless precondition failed (delta too wide)"
         )
-    words = np.asarray(ops.fused_delta_bitpack(xj, bits))
+    words = to_host(ops.fused_delta_bitpack(xj, bits))
     packed = _packed_words_to_bytes(words, x.size, bits).copy()
     # the kernel zero-pads the *input*, so the padding deltas (0 - x[-1]) can
     # smear garbage into the final partial byte; the host bitstream is zero
@@ -667,13 +660,11 @@ register_backend_codec(
 
 def _shuffle_planes(s: Stream) -> np.ndarray:
     """(w, n) byte planes of a fixed-width stream via the byteshuffle kernel."""
-    import jax.numpy as jnp
-
     from repro.kernels import ops
 
     raw = np.frombuffer(s.content_bytes(), dtype=np.uint8)
     mat = raw.reshape(-1, s.width)
-    return np.asarray(ops.byteshuffle(jnp.asarray(mat)))
+    return to_host(ops.byteshuffle(to_device(mat)))
 
 
 def _transpose_applies_device(streams, params):

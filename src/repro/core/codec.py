@@ -27,6 +27,7 @@ import threading
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+from repro.device import span
 from repro.reliability.faults import fault_point
 
 from .message import Stream
@@ -262,7 +263,8 @@ def run_encode_via(
     Returns ``(outs, header, encoded_by)``: ``encoded_by`` names the backend
     that actually ran the node (``"host"`` when the node was routed back), so
     callers can count what ran where.  Backend output passes the same
-    postconditions as the host encoder.
+    postconditions as the host encoder.  The encode runs inside the profiler
+    span ``ozl.encode.<backend>.<codec>`` of the backend that ran it.
     """
     params = dict(params or {})
     if backend != HOST_BACKEND:
@@ -272,7 +274,8 @@ def run_encode_via(
             # exactly where a real kernel crash would, so the session-level
             # host failover sees the same thing either way
             fault_point(f"device.encode.{backend}.{spec.name}")
-            outs, header = impl.encode(list(streams), params)
+            with span(f"ozl.encode.{backend}.{spec.name}"):
+                outs, header = impl.encode(list(streams), params)
             if spec.n_outputs >= 0 and len(outs) != spec.n_outputs:
                 raise AssertionError(
                     f"backend {backend}:{spec.name}: produced {len(outs)} outputs,"
@@ -281,7 +284,9 @@ def run_encode_via(
             if not isinstance(header, (bytes, bytearray)):
                 raise AssertionError(f"backend {backend}:{spec.name}: header must be bytes")
             return [o.validate() for o in outs], bytes(header), backend
-    return (*spec.run_encode(streams, params), HOST_BACKEND)
+    with span(f"ozl.encode.{HOST_BACKEND}.{spec.name}"):
+        outs, header = spec.run_encode(streams, params)
+    return outs, header, HOST_BACKEND
 
 
 _loaded = False

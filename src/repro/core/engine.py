@@ -63,6 +63,8 @@ from typing import (
 
 import numpy as np
 
+from repro.device import span
+
 from . import wire
 from .codec import (
     available_backends,
@@ -297,14 +299,21 @@ def _flatten(plan: Plan, ctx: CompressionCtx) -> Tuple[ResolvedStep, ...]:
 _CACHE_MAX = 512
 _cache: "OrderedDict[tuple, ResolvedPlan]" = OrderedDict()
 _cache_lock = threading.Lock()
-_cache_stats = {"hits": 0, "misses": 0}
+_cache_stats = {"hits": 0, "misses": 0, "miss_s": 0.0}
+# nesting depth of resolves on this thread: a selector's trial compressions
+# resolve their candidate plans inside the outer resolve, and ``miss_s``
+# counts the outer one's time only
+_resolving = threading.local()
 
 
 def resolve_cache_info() -> dict:
+    """Cache traffic; ``miss_s`` is the seconds spent resolving what the
+    cache did not answer (outermost resolves only)."""
     with _cache_lock:
         return {
             "hits": _cache_stats["hits"],
             "misses": _cache_stats["misses"],
+            "miss_s": _cache_stats["miss_s"],
             "size": len(_cache),
             "maxsize": _CACHE_MAX,
         }
@@ -315,6 +324,7 @@ def resolve_cache_clear() -> None:
         _cache.clear()
         _cache_stats["hits"] = 0
         _cache_stats["misses"] = 0
+        _cache_stats["miss_s"] = 0.0
 
 
 # Opt-in debug assert: type-check every plan entering resolve() against the
@@ -419,6 +429,28 @@ def _resolve_impl(
                 return hit, True
             _cache_stats["misses"] += 1
 
+    depth = getattr(_resolving, "depth", 0)
+    _resolving.depth = depth + 1
+    t0 = time.perf_counter()
+    try:
+        with span("ozl.resolve"):
+            resolved = _resolve_uncached(plan, items, metas, metas_only, ctx)
+    finally:
+        _resolving.depth = depth
+        if not depth:
+            spent = time.perf_counter() - t0
+            with _cache_lock:
+                _cache_stats["miss_s"] += spent
+    if use_cache:
+        with _cache_lock:
+            _cache[key] = resolved
+            while len(_cache) > _CACHE_MAX:
+                _cache.popitem(last=False)
+    return resolved, False
+
+
+def _resolve_uncached(plan: Plan, items, metas, metas_only: bool,
+                      ctx: CompressionCtx) -> ResolvedPlan:
     plan.validate()
     if _RESOLVE_CHECK:
         _debug_check_plan(plan, metas, ctx)
@@ -434,15 +466,7 @@ def _resolve_impl(
         in_ids = [r.new_edge(s) for s in items]
         r.run_plan(plan, in_ids)
         steps = tuple(r.steps)
-    resolved = ResolvedPlan(
-        len(metas), steps, ctx.format_version, ctx.level, plan.name
-    )
-    if use_cache:
-        with _cache_lock:
-            _cache[key] = resolved
-            while len(_cache) > _CACHE_MAX:
-                _cache.popitem(last=False)
-    return resolved, False
+    return ResolvedPlan(len(metas), steps, ctx.format_version, ctx.level, plan.name)
 
 
 def _all_metas(inputs) -> bool:

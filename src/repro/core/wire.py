@@ -61,6 +61,8 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.device import span
+
 from .message import Stream, SType, from_wire
 
 MAGIC = b"OZLJ"
@@ -143,34 +145,35 @@ def write_frame(
     nodes: Sequence,  # Sequence[ResolvedNode]
     stored: Sequence[Tuple[int, Stream]],
 ) -> bytes:
-    out = bytearray()
-    out += MAGIC
-    out.append(version & 0xFF)
-    write_varint(out, n_inputs)
-    write_varint(out, len(nodes))
-    for node in nodes:
-        write_varint(out, node.codec_id)
-        write_varint(out, len(node.inputs))
-        for e in node.inputs:
-            write_varint(out, e)
-        write_varint(out, node.n_out)
-        write_varint(out, len(node.header))
-        out += node.header
-    write_varint(out, len(stored))
-    for eid, s in stored:
-        write_varint(out, eid)
-        out.append(int(s.stype))
-        write_varint(out, s.width)
-        if s.stype == SType.STRING:
-            lens = s.lengths if s.lengths is not None else np.zeros(0, np.uint32)
-            write_varint(out, int(lens.size))
-            for ln in lens.tolist():
-                write_varint(out, int(ln))
-        payload = s.content_bytes()
-        write_varint(out, len(payload))
-        out += payload
-    out += _struct.pack("<I", zlib.crc32(bytes(out)) & 0xFFFFFFFF)
-    return bytes(out)
+    with span("ozl.wire.write_frame"):
+        out = bytearray()
+        out += MAGIC
+        out.append(version & 0xFF)
+        write_varint(out, n_inputs)
+        write_varint(out, len(nodes))
+        for node in nodes:
+            write_varint(out, node.codec_id)
+            write_varint(out, len(node.inputs))
+            for e in node.inputs:
+                write_varint(out, e)
+            write_varint(out, node.n_out)
+            write_varint(out, len(node.header))
+            out += node.header
+        write_varint(out, len(stored))
+        for eid, s in stored:
+            write_varint(out, eid)
+            out.append(int(s.stype))
+            write_varint(out, s.width)
+            if s.stype == SType.STRING:
+                lens = s.lengths if s.lengths is not None else np.zeros(0, np.uint32)
+                write_varint(out, int(lens.size))
+                for ln in lens.tolist():
+                    write_varint(out, int(ln))
+            payload = s.content_bytes()
+            write_varint(out, len(payload))
+            out += payload
+        out += _struct.pack("<I", zlib.crc32(bytes(out)) & 0xFFFFFFFF)
+        return bytes(out)
 
 
 def read_frame(frame: bytes):

@@ -158,6 +158,27 @@ def render_prometheus(stats: dict) -> bytes:
                 help_=f"{cache_key} traffic.", type_="counter",
             )
 
+    resolve = stats.get("resolve_cache") or {}
+    w.sample(
+        "ozl_resolve_seconds_total", resolve.get("miss_s"),
+        help_="Seconds spent resolving plans the resolve cache did not hold.",
+        type_="counter",
+    )
+    transfers = stats.get("transfers") or {}
+    for direction in ("h2d", "d2h"):
+        w.sample(
+            "ozl_device_transfers_total", transfers.get(direction),
+            {"direction": direction},
+            help_="Host<->device copies of the device backend.", type_="counter",
+        )
+    for direction in ("h2d", "d2h"):
+        w.sample(
+            "ozl_device_transfer_bytes_total", transfers.get(f"{direction}_bytes"),
+            {"direction": direction},
+            help_="Bytes of host<->device copies of the device backend.",
+            type_="counter",
+        )
+
     # degradation state
     for backend, health in sorted((stats.get("backend_health") or {}).items()):
         w.sample(

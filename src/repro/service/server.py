@@ -406,6 +406,7 @@ class RequestCore:
 
     def stats(self) -> dict:
         from repro.core.engine import resolve_cache_info
+        from repro.device import transfer_info
 
         return {
             **self.ping_header(),
@@ -420,6 +421,8 @@ class RequestCore:
             # are observable in production
             "resolve_cache": resolve_cache_info(),
             "coder_cache": self._scratch.table_cache_info(),
+            # the device twins' host<->device copies, counts and bytes
+            "transfers": transfer_info(),
             # degradation state: which device backends are benched, which plan
             # digests tripped their breaker, and how many requests were shed
             "backend_health": self.backend_health.stats(),
