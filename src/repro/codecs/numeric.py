@@ -12,7 +12,8 @@ which backend produced them.
 """
 from __future__ import annotations
 
-from typing import List, Optional
+import threading
+from typing import List, Optional, Tuple
 
 import numpy as np
 
@@ -371,9 +372,68 @@ register_codec(
 
 
 # ------------------------------------------------------------------ tokenize
+_tokenize_lock = threading.Lock()
+_tokenize_paths = {"int_view": 0, "rows": 0, "strings": 0}
+
+
+def _count_tokenize(path: str) -> None:
+    with _tokenize_lock:
+        _tokenize_paths[path] += 1
+
+
+def tokenize_info() -> dict:
+    """Tokenize encodes in this process by the path that grouped their
+    elements: ``int_view`` (rows of up to 8 bytes, one integer each),
+    ``rows`` (wider rows, several 8-byte words each), ``strings``."""
+    with _tokenize_lock:
+        return dict(_tokenize_paths)
+
+
+def _row_keys(mat: np.ndarray) -> np.ndarray:
+    """Sort keys of the (n, w) uint8 rows ``mat``, equal exactly where the
+    rows' bytes are: one unsigned integer a row (zero-extended to 4 or 8
+    bytes for widths 3, 5, 6, 7), or (n, k) uint64 words for w > 8."""
+    n, w = mat.shape
+    if w in UNSIGNED:
+        return mat.view(UNSIGNED[w]).reshape(n)
+    kw = 4 if w < 4 else 8 * -(-w // 8)
+    padded = np.zeros((n, kw), dtype=np.uint8)
+    padded[:, :w] = mat
+    keys = padded.view(UNSIGNED[min(kw, 8)])
+    return keys.reshape(n) if kw <= 8 else keys
+
+
+def _first_occurrence_ids(mat: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """-> (row of each distinct value's first occurrence, in order of first
+    occurrence; each row's id in that order). Only equality of the keys
+    shows, never their sort order, so any sort will do: numpy's radix sort
+    for 1- and 2-byte keys, its default sort for wider ones."""
+    n = mat.shape[0]
+    if n == 0:
+        return np.zeros(0, dtype=np.intp), np.zeros(0, dtype=np.intp)
+    keys = _row_keys(mat)
+    start = np.ones(n, dtype=bool)
+    if keys.ndim == 1:
+        perm = np.argsort(keys, kind="stable" if keys.itemsize <= 2 else None)
+        sk = keys[perm]
+        np.not_equal(sk[1:], sk[:-1], out=start[1:])
+    else:
+        perm = np.lexsort(keys.T)
+        sk = keys[perm]
+        np.any(sk[1:] != sk[:-1], axis=1, out=start[1:])
+    first = np.minimum.reduceat(perm, np.flatnonzero(start))
+    order = np.argsort(first, kind="stable")
+    rank = np.empty_like(order)
+    rank[order] = np.arange(order.size)
+    inv = np.empty(n, dtype=np.intp)
+    inv[perm] = rank[np.cumsum(start) - 1]
+    return first[order], inv
+
+
 def _tokenize_enc(streams, params):
     s = streams[0]
     if s.stype == SType.STRING:
+        _count_tokenize("strings")
         items = s.to_strings()
         seen = {}
         order: List[bytes] = []
@@ -396,14 +456,10 @@ def _tokenize_enc(streams, params):
     raw = np.frombuffer(s.content_bytes(), dtype=np.uint8)
     w = s.width if s.stype != SType.SERIAL else 1
     mat = raw.reshape(-1, w)
+    _count_tokenize("int_view" if w <= 8 else "rows")
     # first-occurrence ordering keeps the alphabet stable for delta-friendly ids
-    uniq, first_idx, inv = np.unique(mat, axis=0, return_index=True, return_inverse=True)
-    order = np.argsort(first_idx, kind="stable")
-    rank = np.empty_like(order)
-    rank[order] = np.arange(order.size)
-    inv = rank[inv]
-    uniq = uniq[order]
-    alphabet = from_wire(s.stype, s.width, np.ascontiguousarray(uniq).tobytes(), None)
+    first, inv = _first_occurrence_ids(mat)
+    alphabet = from_wire(s.stype, s.width, mat[first].tobytes(), None)
     indices = numeric_stream(inv.astype(np.uint32))  # always u32 (see above)
     h = HeaderWriter().u8(0).u8(4).done()
     return [alphabet, indices], h

@@ -1,7 +1,9 @@
 """Scalar reference implementations — the PRE-vectorization codec code.
 
 Copied verbatim from the seed implementations of ``codecs/lz.py`` and
-``codecs/entropy.py`` (commit 09cade9) with codec registration stripped.
+``codecs/entropy.py`` (commit 09cade9) with codec registration stripped,
+and ``_tokenize_rows_ref``: ``codecs/numeric.py``'s tokenize encoder as it
+was while it grouped fixed-width rows with ``np.unique(axis=0)``.
 The cross-check suite (``test_vectorized_equiv.py``) pins the vectorized
 implementations against these: same inputs -> bit-identical output streams
 and headers, which is the wire-compatibility guarantee for every frame any
@@ -16,7 +18,7 @@ from typing import List
 
 import numpy as np
 
-from repro.core.message import Stream, SType
+from repro.core.message import Stream, SType, from_wire
 
 from repro.codecs._util import HeaderReader, HeaderWriter, numeric_stream
 
@@ -575,3 +577,36 @@ def _fse_dec(outs, header):
     return [_rebuild(stype_tag, result)]
 
 
+def _tokenize_rows_ref(streams, params):
+    s = streams[0]
+    if s.stype == SType.STRING:
+        items = s.to_strings()
+        seen = {}
+        order: List[bytes] = []
+        idx = np.empty(len(items), dtype=np.int64)
+        for i, it in enumerate(items):
+            j = seen.get(it)
+            if j is None:
+                j = len(order)
+                seen[it] = j
+                order.append(it)
+            idx[i] = j
+        from repro.core.message import strings as mk_strings
+
+        alphabet = mk_strings(order)
+        indices = numeric_stream(idx.astype(np.uint32))
+        h = HeaderWriter().u8(1).u8(4).done()
+        return [alphabet, indices], h
+    raw = np.frombuffer(s.content_bytes(), dtype=np.uint8)
+    w = s.width if s.stype != SType.SERIAL else 1
+    mat = raw.reshape(-1, w)
+    uniq, first_idx, inv = np.unique(mat, axis=0, return_index=True, return_inverse=True)
+    order = np.argsort(first_idx, kind="stable")
+    rank = np.empty_like(order)
+    rank[order] = np.arange(order.size)
+    inv = rank[inv]
+    uniq = uniq[order]
+    alphabet = from_wire(s.stype, s.width, np.ascontiguousarray(uniq).tobytes(), None)
+    indices = numeric_stream(inv.astype(np.uint32))
+    h = HeaderWriter().u8(0).u8(4).done()
+    return [alphabet, indices], h

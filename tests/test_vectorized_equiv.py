@@ -17,7 +17,8 @@ from _hyp import given, settings, st
 import _scalar_ref as sr
 from repro.codecs import entropy as vec_entropy
 from repro.codecs import lz as vec_lz
-from repro.core.message import serial
+from repro.codecs import numeric as vec_numeric
+from repro.core.message import numeric, serial, strings, struct
 
 
 def _assert_bitwise_equal(codec, data):
@@ -170,3 +171,117 @@ def test_fse_large_table_log_flush():
             assert a.data.tobytes() == b.data.tobytes(), table_log
         back = vec_entropy._fse_dec(new_outs, new_h)[0].content_bytes()
         assert back == data, table_log
+
+
+def _assert_tokenize_equal(s):
+    """Tokenize's alphabet, u32 indices and header are byte for byte the
+    row-unique encoder's, and decode back to the input."""
+    ref_outs, ref_h = sr._tokenize_rows_ref([s], {})
+    new_outs, new_h = vec_numeric._tokenize_enc([s], {})
+    assert ref_h == new_h
+    assert len(ref_outs) == len(new_outs) == 2
+    for a, b in zip(ref_outs, new_outs):
+        assert (a.stype, a.width) == (b.stype, b.width)
+        assert a.data.dtype == b.data.dtype
+        assert a.data.tobytes() == b.data.tobytes()
+    back = vec_numeric._tokenize_dec(new_outs, new_h)[0]
+    assert (back.stype, back.width) == (s.stype, s.width)
+    assert back.content_bytes() == s.content_bytes()
+
+
+def _repeats(rng, n, width, k):
+    """``n`` rows of ``width`` bytes drawn from ``k`` random distinct-ish rows."""
+    alphabet = rng.integers(0, 256, (k, width), dtype=np.uint8)
+    return alphabet[rng.integers(0, k, n)].reshape(-1)
+
+
+def _tokenize_case(name):
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    sized = {"u8": np.uint8, "u16": np.uint16, "u32": np.uint32, "u64": np.uint64}
+    if name in sized:
+        dt = sized[name]
+        return numeric(rng.integers(0, 40, 5000).astype(dt) * dt(3))
+    if name.startswith("struct"):
+        return struct(_repeats(rng, 4000, int(name[6:]), 37), int(name[6:]))
+    cases = {
+        "serial": lambda: serial(bytes(_repeats(rng, 9000, 1, 23))),
+        "empty_numeric": lambda: numeric(np.zeros(0, dtype=np.int64)),
+        "empty_struct3": lambda: struct(b"", 3),
+        "empty_serial": lambda: serial(b""),
+        "single_u64": lambda: numeric(np.array([7], dtype=np.uint64)),
+        "single_struct12": lambda: struct(bytes(range(12)), 12),
+        "all_equal_u32": lambda: numeric(np.full(3000, 0xDEADBEEF, dtype=np.uint32)),
+        "all_equal_struct3": lambda: struct(b"abc" * 3000, 3),
+        "all_distinct_u64": lambda: numeric(rng.permutation(4000).astype(np.uint64) << np.uint64(40)),
+        "all_distinct_struct12": lambda: struct(
+            rng.permutation(5000).astype("<u4").view(np.uint8).repeat(3), 12),
+        "l_quantity": lambda: numeric(rng.integers(1, 51, 60000).astype(np.int64) * 100),
+        "negative_i64": lambda: numeric(rng.integers(-(1 << 62), 1 << 62, 3000).astype(np.int64)
+                                        .repeat(2) * np.int64(-1)),
+        # little-endian byte order and integer order disagree: 0x0100 sorts
+        # above 0x0001 as an integer but below it as bytes, so ids must not
+        # follow either sort
+        "byte_order_u16": lambda: numeric(np.array([0x0100, 0x0001, 0x0100, 0x00FF, 0xFF00, 0x0001],
+                                                   dtype=np.uint16)),
+        "byte_order_u64": lambda: numeric(np.array([1 << 56, 1, 1 << 8, 1, 1 << 56, 255, 1 << 63],
+                                                   dtype=np.uint64)),
+        "byte_order_struct3": lambda: struct(bytes([1, 0, 0, 0, 0, 1, 0, 1, 0, 1, 0, 0, 0, 0, 1]), 3),
+        "byte_order_struct12": lambda: struct(
+            np.array([[0] * 11 + [1], [1] + [0] * 11, [0] * 8 + [1, 0, 0, 0], [0] * 11 + [1]],
+                     dtype=np.uint8).reshape(-1), 12),
+    }
+    return cases[name]()
+
+
+TOKENIZE_CASES = [
+    "u8", "u16", "u32", "u64",
+    "struct2", "struct3", "struct4", "struct8", "struct12", "struct5", "struct16", "serial",
+    "empty_numeric", "empty_struct3", "empty_serial", "single_u64", "single_struct12",
+    "all_equal_u32", "all_equal_struct3", "all_distinct_u64", "all_distinct_struct12",
+    "l_quantity", "negative_i64",
+    "byte_order_u16", "byte_order_u64", "byte_order_struct3", "byte_order_struct12",
+]
+
+
+@pytest.mark.parametrize("case", TOKENIZE_CASES)
+def test_tokenize_matches_row_unique(case):
+    _assert_tokenize_equal(_tokenize_case(case))
+
+
+@given(st.sampled_from(["numeric", "struct", "serial"]),
+       st.sampled_from([1, 2, 3, 4, 5, 6, 7, 8, 9, 12, 16, 17]),
+       st.integers(0, 600), st.integers(1, 300), st.integers(0, 2**32 - 1))
+@settings(max_examples=60, deadline=None)
+def test_tokenize_matches_row_unique_random(kind, width, n, k, seed):
+    rng = np.random.default_rng(seed)
+    if kind == "numeric":
+        width = next(w for w in (1, 2, 4, 8) if w >= min(width, 8))
+        s = numeric(_repeats(rng, n, width, k).view(f"<u{width}"))
+    elif kind == "struct":
+        s = struct(_repeats(rng, n, width, k), width)
+    else:
+        s = serial(bytes(_repeats(rng, n, 1, k)))
+    _assert_tokenize_equal(s)
+
+
+def test_tokenize_info_counts_paths():
+    """One ``int_view`` per encode of rows up to 8 bytes wide (widths 3 and
+    5 zero-extended), ``rows`` for wider rows, ``strings`` for STRING."""
+    streams = [
+        (numeric(np.arange(10, dtype=np.uint8)), "int_view"),
+        (numeric(np.arange(10, dtype=np.uint16)), "int_view"),
+        (numeric(np.arange(10, dtype=np.uint32)), "int_view"),
+        (numeric(np.arange(10, dtype=np.int64)), "int_view"),
+        (struct(b"abcabcxyz", 3), "int_view"),
+        (struct(bytes(15), 5), "int_view"),
+        (serial(b"hello"), "int_view"),
+        (struct(bytes(range(24)), 12), "rows"),
+        (strings([b"a", b"bb", b"a"]), "strings"),
+    ]
+    for s, path in streams:
+        before = vec_numeric.tokenize_info()
+        vec_numeric._tokenize_enc([s], {})
+        after = vec_numeric.tokenize_info()
+        assert {k: after[k] - before[k] for k in after} == {
+            k: int(k == path) for k in after
+        }, (s, path)
