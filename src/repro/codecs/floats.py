@@ -14,6 +14,8 @@ fp32 checkpoints and 30% on bf16 embeddings from exactly this transform.
 """
 from __future__ import annotations
 
+import threading
+
 import numpy as np
 
 from repro.core.codec import (
@@ -44,6 +46,23 @@ FORMATS = {
 _FMT_BY_WIDTH = {2: 0, 4: 2, 8: 3}  # default fmt per width (bf16 for w=2)
 _EXP_DTYPE = {0: np.uint8, 1: np.uint8, 2: np.uint8, 3: np.uint16}
 _MAN_DTYPE = {0: np.uint8, 1: np.uint16, 2: np.uint32, 3: np.uint64}
+_UINT = {2: np.uint16, 4: np.uint32, 8: np.uint64}
+
+_split_lock = threading.Lock()
+_split_elements = {"device": {}, "host": {}}
+
+
+def _count_split(backend: str, fmt: int, n: int) -> None:
+    with _split_lock:
+        per = _split_elements[backend]
+        per[fmt] = per.get(fmt, 0) + int(n)
+
+
+def float_split_info() -> dict:
+    """Elements split by float_split encodes in this process, by the backend
+    that split them (``device``, ``host``) and then by fmt tag (``FORMATS``)."""
+    with _split_lock:
+        return {backend: dict(per) for backend, per in _split_elements.items()}
 
 
 def _pack_sign_bits(sign: np.ndarray) -> np.ndarray:
@@ -60,7 +79,8 @@ def _float_split_enc(streams, params):
     width, exp_bits, man_bits = FORMATS[fmt]
     if width != s.width:
         raise ValueError(f"float_split fmt {fmt} expects width {width}")
-    u = s.data.view({2: np.uint16, 4: np.uint32, 8: np.uint64}[s.width])
+    u = s.data.view(_UINT[s.width])
+    _count_split("host", fmt, u.size)
     tot = exp_bits + man_bits
     sign = (u >> np.uint64(tot)).astype(np.uint8) & 1
     exp = ((u >> np.uint64(man_bits)) & np.uint64((1 << exp_bits) - 1)).astype(
@@ -86,9 +106,7 @@ def _float_split_dec(outs, header):
     exp = exp_s.data.astype(np.uint64)
     man = man_s.data.astype(np.uint64)
     u = (sign << np.uint64(exp_bits + man_bits)) | (exp << np.uint64(man_bits)) | man
-    out = u.astype(np.uint64).astype(
-        {2: np.uint16, 4: np.uint32, 8: np.uint64}[width]
-    )
+    out = u.astype(np.uint64).astype(_UINT[width])
     return [numeric_stream(out)]
 
 
@@ -133,23 +151,29 @@ register_codec(
 
 
 # --------------------------------------------------------------- device twin
-# The float_split Pallas kernel works on u32 lanes, i.e. fmt 2 (float32);
-# other formats fall back to the host encoder.  Output planes and header are
-# bit-identical to the host path.
+# The float_split Pallas kernel works on u32 lanes: bf16/f16 (fmt 0/1) bit
+# patterns are widened to them, f32 (fmt 2) fills them, and f64 (fmt 3) falls
+# back to the host encoder.  The planes come back in the host encoder's dtypes
+# under the same header, so frames are byte-identical to the host path.
+_DEVICE_FMTS = (0, 1, 2)
+
+
 def _float_split_applies_device(streams, params):
     s = streams[0]
-    if not (device_available() and s.stype == SType.NUMERIC and s.width == 4):
+    if not (device_available() and s.stype == SType.NUMERIC):
         return False
-    return int(params.get("fmt", _FMT_BY_WIDTH.get(s.width, -1))) == 2
+    fmt = int(params.get("fmt", _FMT_BY_WIDTH.get(s.width, -1)))
+    return fmt in _DEVICE_FMTS and FORMATS[fmt][0] == s.width
 
 
 def _float_split_enc_device(streams, params):
     from repro.kernels import ops
 
     s = streams[0]
-    fmt = 2
+    fmt = int(params.get("fmt", _FMT_BY_WIDTH[s.width]))
     _width, exp_bits, man_bits = FORMATS[fmt]
-    u = s.data.view(np.uint32)
+    u = s.data.view(_UINT[s.width])
+    _count_split("device", fmt, u.size)
     sign, exp, man = ops.float_split(to_device(u), exp_bits, man_bits)
     h = HeaderWriter().u8(fmt).varint(u.size).done()
     return [

@@ -38,16 +38,20 @@ from repro.codecs import (
 )
 from repro.core import CompressorSession, DecompressorSession, numeric
 from repro.core.graph import Plan, pipeline as plan_pipeline
+from repro.device import on_tpu
 from repro.reliability.faults import crash_point
 
 MANIFEST = "manifest.json"
+# leaves are compressed in chunks of this many bytes (the CLI's default)
+CHUNK_BYTES = 4 << 20
 
 # ------------------------------------------------- long-lived codec sessions
 # One CompressorSession per distinct leaf plan and one shared
 # DecompressorSession per worker process: thousands of checkpoint leaves reuse
 # the same resolve cache, coder-table scratch, and thread pool instead of
 # paying session construction per leaf.  Sessions are thread-safe, so the
-# async-save background thread shares them with the restore path.
+# async-save background thread shares them with the restore path.  On a TPU
+# the encode sessions run on the device backend; elsewhere on the host.
 _SESSION_LOCK = threading.Lock()
 _ENC_SESSIONS: Dict[Plan, CompressorSession] = {}
 _DEC_SESSION: list = []  # 0 or 1 DecompressorSession
@@ -57,7 +61,10 @@ def _enc_session(plan: Plan) -> CompressorSession:
     with _SESSION_LOCK:
         sess = _ENC_SESSIONS.get(plan)
         if sess is None:
-            sess = _ENC_SESSIONS[plan] = CompressorSession(plan)
+            backend = "device" if on_tpu() else "host"
+            sess = _ENC_SESSIONS[plan] = CompressorSession(
+                plan, backend=backend, chunk_bytes=CHUNK_BYTES
+            )
         return sess
 
 

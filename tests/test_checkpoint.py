@@ -128,3 +128,36 @@ def test_elastic_restore_resharding(tmp_path, tree):
     assert all(
         isinstance(x, jax.Array) for x in jax.tree.leaves(restored)
     )
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_compress_leaf_on_a_tpu_gives_the_benchmark_session_frame(monkeypatch, tmp_path, dtype):
+    """Where the one device decision answers "TPU", a leaf goes through a
+    device-backend session in 4 MiB chunks: the same frame as
+    ``CompressorSession(<dtype profile>, backend="device")`` chunked at
+    4 MiB, which is the checkpoint cell's call; restore reads it back."""
+    from repro.codecs import bfloat16_profile, float32_profile
+    from repro.core import CompressorSession, numeric
+    from repro.distributed import checkpoint
+
+    n = (5 << 20) // np.dtype(jnp.bfloat16 if dtype == "bfloat16" else np.float32).itemsize
+    leaf = np.asarray(jnp.asarray(0.02 * rng.normal(size=n), dtype))  # two chunks
+    bits = leaf.view(np.uint16 if dtype == "bfloat16" else np.uint32)
+    profile = bfloat16_profile() if dtype == "bfloat16" else float32_profile()
+    cell = CompressorSession(profile, backend="device")
+    try:
+        want = cell.compress(numeric(bits), chunk_bytes=4 << 20)
+    finally:
+        cell.close()
+    checkpoint.close_codec_sessions()
+    monkeypatch.setattr(checkpoint, "on_tpu", lambda: True)
+    try:
+        assert compress_leaf(leaf) == want
+        (sess,) = checkpoint._ENC_SESSIONS.values()
+        assert sess.backend == "device" and sess.stats["chunks"] == 2
+        assert "float_split" not in sess.stats["nodes"].get("host", {})
+        save_checkpoint(tmp_path, 1, {"w": leaf})
+        restored, _ = restore_checkpoint(tmp_path, 1)
+    finally:
+        checkpoint.close_codec_sessions()
+    assert restored["w"].dtype == leaf.dtype and np.array_equal(restored["w"], leaf)

@@ -61,6 +61,7 @@ def _compile(one_chip, fn, *shapes):
 
 
 U32 = ((N,), jnp.uint32)
+U16 = ((N,), jnp.uint16)
 U8 = ((N,), jnp.uint8)
 TABLE_U32 = ((256,), jnp.uint32)
 TABLE_I32 = ((256,), jnp.int32)
@@ -69,7 +70,7 @@ TABLE_I32 = ((256,), jnp.int32)
 # ------------------------------------------------------------ Pallas kernels
 @pytest.mark.parametrize(
     "case",
-    ["delta_encode", "float_split", "huffman_map"]
+    ["delta_encode", "float_split", "float_split_bf16", "float_split_f16", "huffman_map"]
     + [f"bitpack{b}" for b in (1, 2, 4, 8, 16)]
     + [f"fused_delta_bitpack{b}" for b in (1, 2, 4, 8, 16)]
     + [f"byteshuffle{w}" for w in (1, 4, 8)],
@@ -80,6 +81,10 @@ def test_pallas_kernel_compiles_for_v5e(one_chip, tpu_ops, case):
         text = _compile(one_chip, ops.delta_encode, U32)
     elif case == "float_split":
         text = _compile(one_chip, lambda u: ops.float_split(u, 8, 23), U32)
+    elif case == "float_split_bf16":  # bf16 bit patterns, widened in the wrapper
+        text = _compile(one_chip, lambda u: ops.float_split(u, 8, 7), U16)
+    elif case == "float_split_f16":
+        text = _compile(one_chip, lambda u: ops.float_split(u, 5, 10), U16)
     elif case == "huffman_map":
         text = _compile(one_chip, ops.huffman_map, U8, TABLE_U32, TABLE_I32)
     elif case.startswith("bitpack"):
