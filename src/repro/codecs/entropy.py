@@ -42,7 +42,7 @@ pre-vectorization implementation):
 from __future__ import annotations
 
 import heapq
-from typing import List, Tuple
+from typing import List, NamedTuple, Tuple
 
 import numpy as np
 
@@ -442,6 +442,13 @@ def _build_tables(norm: np.ndarray, table_log: int):
     Slot-order occurrence ranks come from a stable argsort of the spread:
     slots grouped by symbol, slot order preserved inside each group — which
     is exactly the x' = norm[s]+k numbering of the serial construction.
+
+    Returns (dec_sym, dec_nb, dec_base, enc_table, state_table, delta).
+    ``state_table`` is that argsort, FSE's compact ``stateTable``: symbol
+    s's row of ``enc_table`` is ``state_table[group_start[s]:][:norm[s]]``,
+    so the next state from x' is ``state_table[x' + delta[s]]`` with
+    ``delta = group_start - norm`` (2^table_log entries in all, where
+    ``enc_table`` pads every symbol to max(norm)).
     """
     total = 1 << table_log
     spread = _spread_symbols(norm, table_log)
@@ -461,25 +468,41 @@ def _build_tables(norm: np.ndarray, table_log: int):
     width = int(norm.max()) if norm.max() else 1
     enc_table = np.zeros((norm.size, width), dtype=np.int32)
     enc_table[sym_sorted, rank] = order
-    return dec_sym, dec_nb, dec_base, enc_table
+    state_table = order.astype(np.int32)
+    delta = (group_start - norm).astype(np.int32)
+    return dec_sym, dec_nb, dec_base, enc_table, state_table, delta
 
 
-def _fse_tables_cached(norm: np.ndarray, table_log: int):
-    """All FSE tables for (norm, table_log), memoized in the active cache.
+class FseTables(NamedTuple):
+    """Every FSE table for one (norm, table_log); see ``_build_tables``.
 
-    Returns (dec_sym, dec_nb, dec_base, enc_table, nb0, thr, st0): the last
-    three are the per-symbol encode helpers — nb0/thr give the emitted bit
-    count as ``nb0 - (X < thr)`` without any per-position bit-length loop,
-    st0 is the lane-start state.
+    nb0/thr give the emitted bit count as ``nb0 - (X < thr)`` without any
+    per-position bit-length loop; st0 is the lane-start state.  The host
+    encoder steps through ``enc_table``, the device walk through
+    ``state_table`` and ``delta``.
     """
 
+    dec_sym: np.ndarray
+    dec_nb: np.ndarray
+    dec_base: np.ndarray
+    enc_table: np.ndarray
+    state_table: np.ndarray
+    delta: np.ndarray
+    nb0: np.ndarray
+    thr: np.ndarray
+    st0: np.ndarray
+
+
+def _fse_tables_cached(norm: np.ndarray, table_log: int) -> FseTables:
+    """All FSE tables for (norm, table_log), memoized in the active cache."""
+
     def build():
-        dec_sym, dec_nb, dec_base, enc_table = _build_tables(norm, table_log)
-        bl = _bit_length(norm)
-        nb0 = (table_log + 1) - bl
-        thr = norm << np.maximum(nb0, 0)
+        tables = _build_tables(norm, table_log)
+        enc_table = tables[3]
+        nb0 = (table_log + 1) - _bit_length(norm)
+        thr = (norm << np.maximum(nb0, 0)).astype(np.int32)
         st0 = enc_table[:, 0].copy()
-        return _freeze(dec_sym, dec_nb, dec_base, enc_table, nb0, thr, st0)
+        return FseTables(*_freeze(*tables, nb0.astype(np.int32), thr, st0))
 
     return active_cache().get_or_build(
         ("fse", norm.tobytes(), table_log), build
@@ -500,9 +523,8 @@ def _fse_enc(streams, params):
     with _stage("table_build"):
         counts = _hist_u8(x)
         norm = _normalize_counts(counts, table_log)
-        (
-            _dec_sym, _dec_nb, _dec_base, enc_table, nb0t, thrt, st0t,
-        ) = _fse_tables_cached(norm, table_log)
+        tabs = _fse_tables_cached(norm, table_log)
+    enc_table, nb0t, thrt, st0t = tabs.enc_table, tabs.nb0, tabs.thr, tabs.st0
     total = 1 << table_log
 
     block = 1 << FSE_BLOCK_LOG
@@ -611,9 +633,8 @@ def _fse_dec(outs, header):
         s = tbl.varint()
         norm[s] = tbl.varint()
     with _stage("table_build"):
-        dec_sym, dec_nb, dec_base, _enc, _nb0, _thr, _st0 = _fse_tables_cached(
-            norm, table_log
-        )
+        tabs = _fse_tables_cached(norm, table_log)
+    dec_sym, dec_nb, dec_base = tabs.dec_sym, tabs.dec_nb, tabs.dec_base
 
     block = 1 << block_log
     n_blocks = (n + block - 1) // block

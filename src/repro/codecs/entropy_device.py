@@ -16,6 +16,8 @@ exercised by the kernel equivalence tests.
 """
 from __future__ import annotations
 
+import threading
+
 import numpy as np
 
 from repro.core.codec import register_backend_codec
@@ -104,6 +106,24 @@ def _fse_applies_device(streams, params):
     return _dev_entropy_ready(streams)
 
 
+_fse_lock = threading.Lock()
+_fse_counts = {"calls": 0, "symbols": 0, "lane_steps": 0}
+
+
+def _count_fse(n_symbols: int, lane_steps: int) -> None:
+    with _fse_lock:
+        _fse_counts["calls"] += 1
+        _fse_counts["symbols"] += n_symbols
+        _fse_counts["lane_steps"] += lane_steps
+
+
+def fse_device_info() -> dict:
+    """Device fse encodes in this process: calls, symbols encoded, and
+    lane-steps walked (lanes x (1 << FSE_BLOCK_LOG) per call)."""
+    with _fse_lock:
+        return dict(_fse_counts)
+
+
 def _fse_enc_device(streams, params):
     from repro.kernels import ops
 
@@ -114,9 +134,7 @@ def _fse_enc_device(streams, params):
     xj = to_device(x)
     counts = to_host(ops.histogram_exact(xj)).astype(np.int64)
     norm = _normalize_counts(counts, table_log)
-    _ds, _dn, _db, enc_table, nb0t, thrt, st0t = _fse_tables_cached(norm, table_log)
-    total = 1 << table_log
-    width = enc_table.shape[1]
+    tabs = _fse_tables_cached(norm, table_log)
 
     block = 1 << FSE_BLOCK_LOG
     n_blocks = (n + block - 1) // block
@@ -129,14 +147,13 @@ def _fse_enc_device(streams, params):
     vals, goffs, state, bitpos, byte_off = ops.fse_encode(
         to_device(lanesT),
         to_device(rem),
-        to_device(nb0t.astype(np.int32)),
-        to_device(thrt.astype(np.int32)),
-        to_device(st0t.astype(np.int32)),
-        to_device(norm.astype(np.int32)),
-        to_device(enc_table.reshape(-1)),
-        width,
-        total,
+        to_device(tabs.nb0),
+        to_device(tabs.thr),
+        to_device(tabs.st0),
+        to_device(tabs.delta),
+        to_device(tabs.state_table),
     )
+    _count_fse(n, n_blocks * block)
     total_bytes = int(to_host(byte_off[-1]))
     stream_out = to_host(
         ops.pack_bits(

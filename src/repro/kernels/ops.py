@@ -289,28 +289,58 @@ def huffman_decode(
 
 
 # -------------------------------------------------------------- entropy: fse
-@functools.partial(jax.jit, static_argnames=("width", "total"))
+@functools.partial(jax.jit, static_argnames=("use_pallas",))
 def fse_encode(
     lanesT: jax.Array,
     rem: jax.Array,
     nb0: jax.Array,
     thr: jax.Array,
     st0: jax.Array,
-    norm: jax.Array,
-    enc_flat: jax.Array,
-    width: int,
-    total: int,
+    delta: jax.Array,
+    state_table: jax.Array,
+    *,
+    use_pallas: Flag = None,
 ):
-    """tANS backward scan + wire-layout bit offsets.
+    """tANS backward walk on the compact state table + wire-layout offsets.
 
-    Returns (vals u32 planes, global bit offsets i32 planes, final states,
-    per-lane bit lengths, lane byte offsets i32[n+1]).  The offsets place
-    every emission directly into the *concatenated* per-lane bitstream
-    layout the host encoder produces, so one ``pack_bits`` call yields the
-    final wire bytes.  Plain XLA on every backend (see kernels/fse.py)."""
-    vals, nbs, state = ref.fse_encode_lanes(
-        lanesT, rem.astype(jnp.int32), nb0, thr, st0, norm, enc_flat, width, total
-    )
+    ``lanesT`` is (1 << FSE_BLOCK_LOG, n_lanes) symbols, ``rem`` each lane's
+    length; nb0, thr, st0 and delta are the per-symbol helpers and
+    ``state_table`` the 2^table_log compact table of ``_build_tables``.
+    The walk carries only the lane states and takes one gather from the
+    compact table a step (kernels/fse.py; ``ref.fse_encode_lanes`` is its
+    jnp oracle).  Returns (vals u32 planes, global bit offsets i32 planes,
+    final states, per-lane bit lengths, lane byte offsets i32[n+1]).  The
+    offsets place every emission directly into the *concatenated* per-lane
+    bitstream layout the host encoder produces, so one ``pack_bits`` call
+    yields the final wire bytes."""
+    from .fse import ENC_LANES, LANES, fse_encode_pallas
+
+    rem = rem.astype(jnp.int32)
+    if not _pallas(use_pallas):
+        vals, nbs, state = ref.fse_encode_lanes(
+            lanesT, rem, nb0, thr, st0, delta, state_table
+        )
+    else:
+        max_rem, n = lanesT.shape
+        pad = (-n) % ENC_LANES
+        sym = jnp.pad(lanesT.astype(jnp.int32), ((0, 0), (0, pad)))
+        # a lane of length r starts at position r-1 in state st0[sym]
+        start = sym[jnp.maximum(rem - 1, 0), jnp.arange(n)]
+        init = _pad_to(jnp.take(st0.astype(jnp.int32), start), ENC_LANES)
+        nbthr = nb0.astype(jnp.int32) | (thr.astype(jnp.int32) << 5)
+        lanes3 = lambda a: a.reshape(a.shape[:-1] + (-1, LANES))
+        vals, nbs, state = fse_encode_pallas(
+            lanes3(sym),
+            lanes3(_pad_to(rem, ENC_LANES)),
+            lanes3(init),
+            nbthr,
+            delta.astype(jnp.int32),
+            state_table.astype(jnp.int32),
+            interpret=_interpret(),
+        )
+        vals = vals.reshape(max_rem, -1)[:, :n].astype(jnp.uint32)
+        nbs = nbs.reshape(max_rem, -1)[:, :n]
+        state = state.reshape(-1)[:n]
     bitpos = jnp.sum(nbs, axis=0, dtype=jnp.int32)
     # emission order is decreasing position i, so the offset of emission i
     # within its lane is the suffix sum of later positions' bit counts

@@ -152,53 +152,54 @@ def fse_encode_lanes(
     nb0: jax.Array,
     thr: jax.Array,
     st0: jax.Array,
-    norm: jax.Array,
-    enc_flat: jax.Array,
-    width: int,
-    total: int,
+    delta: jax.Array,
+    state_table: jax.Array,
 ):
     """tANS backward state walk, one vector lane per block (paper §II-A;
     state machine after the SCL FSE exemplar).
 
     ``lanesT`` is (max_rem, n_lanes) symbols; a lane of length r initializes
     its state at position r-1 and emits the low bits of its state for every
-    earlier position.  Returns per-position (vals u32, nbits i32) planes plus
-    the final per-lane states — the bit-I/O composition (offsets + packing)
-    happens in ``pack_bits`` on the same device.  Arithmetic is all int32:
-    states live in [0, 2*2^table_log).
+    earlier position.  The per-symbol helpers are gathered once over the
+    whole plane; the loop carries only the lane states and steps through the
+    2^table_log compact ``state_table`` (``_build_tables``), recording
+    X = state + 2^table_log at every position.  Bit counts and values follow
+    from the recorded plane after the loop.  Returns per-position (vals u32,
+    nbits i32) planes plus the final per-lane states — the bit-I/O
+    composition (offsets + packing) happens in ``pack_bits`` on the same
+    device.  Arithmetic is all int32: states live in [0, 2*2^table_log).
     """
     max_rem, n_lanes = lanesT.shape
-    nb0 = nb0.astype(jnp.int32)
-    thr = thr.astype(jnp.int32)
-    st0 = st0.astype(jnp.int32)
-    norm = norm.astype(jnp.int32)
-    enc_flat = enc_flat.astype(jnp.int32)
+    total = state_table.shape[0]
+    s = lanesT.astype(jnp.int32)
+    nb0s = jnp.take(nb0.astype(jnp.int32), s)
+    thrs = jnp.take(thr.astype(jnp.int32), s)
+    deltas = jnp.take(delta.astype(jnp.int32), s)
+    st0s = jnp.take(st0.astype(jnp.int32), s)
+    pos = jnp.arange(max_rem, dtype=jnp.int32)
     rem = rem.astype(jnp.int32)
-    vals0 = jnp.zeros((max_rem, n_lanes), jnp.uint32)
-    nbs0 = jnp.zeros((max_rem, n_lanes), jnp.int32)
+    stab = state_table.astype(jnp.int32)
 
-    def step(j, carry):
-        state, vals, nbs = carry
-        i = max_rem - 1 - j
-        s = lanesT[i].astype(jnp.int32)
-        emit = rem > i + 1
+    def step(state, row):
+        i, nb0_i, thr_i, delta_i, st0_i = row
         X = state + total
-        nb = jnp.take(nb0, s) - (X < jnp.take(thr, s)).astype(jnp.int32)
-        nbe = jnp.where(emit, nb, 0)
-        val = X.astype(jnp.uint32) & (
-            (jnp.uint32(1) << nbe.astype(jnp.uint32)) - jnp.uint32(1)
-        )
-        vals = vals.at[i].set(val)
-        nbs = nbs.at[i].set(nbe)
-        xprime = jnp.clip((X >> nb) - jnp.take(norm, s), 0, width - 1)
-        new_state = jnp.take(enc_flat, s * width + xprime)
+        nb = nb0_i - (X < thr_i).astype(jnp.int32)
+        nxt = jnp.take(stab, jnp.clip((X >> nb) + delta_i, 0, total - 1))
         state = jnp.where(
-            emit, new_state, jnp.where(rem == i + 1, jnp.take(st0, s), state)
+            rem > i + 1, nxt, jnp.where(rem == i + 1, st0_i, state)
         )
-        return state, vals, nbs
+        return state, X
 
-    state, vals, nbs = jax.lax.fori_loop(
-        0, max_rem, step, (jnp.zeros(n_lanes, jnp.int32), vals0, nbs0)
+    state, X = jax.lax.scan(
+        step,
+        jnp.zeros(n_lanes, jnp.int32),
+        (pos, nb0s, thrs, deltas, st0s),
+        reverse=True,
+    )
+    emit = rem[None, :] > pos[:, None] + 1
+    nbs = jnp.where(emit, nb0s - (X < thrs).astype(jnp.int32), 0)
+    vals = X.astype(jnp.uint32) & (
+        (jnp.uint32(1) << nbs.astype(jnp.uint32)) - jnp.uint32(1)
     )
     return vals, nbs, state
 

@@ -279,38 +279,60 @@ def _fse_fixture(n, table_log=11, seed=3):
     return data, norm, tabs
 
 
-@pytest.mark.parametrize("n", [1, 100, 1025, 50000])
-def test_fse_encode_kernel_matches_host_encoder(n):
-    """Device backward scan (plain XLA on every backend) + packer == the
-    host tANS encoder's bitstream and (bit length, final state) meta, byte
-    for byte."""
+def _exponent_plane(kind, n, seed=5):
+    """fp32 exponent bytes as the checkpoint cell makes them: N(0, 0.02)
+    weights, squared N(0, 1e-3) second moments; zipf bytes; one symbol."""
+    r = np.random.default_rng(seed)
+    if kind == "skewed":
+        return _skewed(n, seed=seed)
+    if kind == "one_symbol":
+        return np.full(n, 121, np.uint8)
+    f = r.normal(0.0, 0.02 if kind == "weights" else 1e-3, n).astype(np.float32)
+    if kind == "sq_moments":
+        f = np.square(f)
+    return ((f.view(np.uint32) >> 23) & 0xFF).astype(np.uint8)
+
+
+FSE_TAIL = 3 * 1024 + 17
+FSE_ENCODE_CASES = (
+    [("skewed", n, 11) for n in (1, 100, 1023, 1024, 1025, FSE_TAIL, 50000)]
+    + [("weights", FSE_TAIL, tl) for tl in (5, 9, 11, 12)]
+    + [("sq_moments", FSE_TAIL, tl) for tl in (5, 9, 11, 12)]
+    + [("weights", 1 << 16, 11), ("sq_moments", 1 << 16, 11)]
+    + [("one_symbol", n, tl) for n, tl in ((1, 11), (1025, 5), (FSE_TAIL, 12))]
+)
+
+
+@pytest.mark.parametrize("use_pallas", [True, False])
+@pytest.mark.parametrize("kind,n,table_log", FSE_ENCODE_CASES)
+def test_fse_encode_kernel_matches_host_encoder(kind, n, table_log, use_pallas):
+    """Device backward walk on the compact state table (Pallas kernel and
+    its jnp oracle) + packer == the host tANS encoder's bitstream and
+    (bit length, final state) meta, byte for byte."""
     from repro.codecs import entropy as E
     from repro.core.message import Stream, SType
 
-    table_log = 11
-    data, norm, _ = _fse_fixture(n, table_log)
-    _ds, _dn, _db, enc_table, nb0t, thrt, st0t = E._fse_tables_cached(
-        norm, table_log
-    )
-    total = 1 << table_log
-    width = enc_table.shape[1]
+    data = _exponent_plane(kind, n)
+    norm = E._normalize_counts(E._hist_u8(data), table_log)
+    tabs = E._fse_tables_cached(norm, table_log)
     block = 1 << E.FSE_BLOCK_LOG
     n_blocks = (n + block - 1) // block
     padded = np.zeros(n_blocks * block, np.uint8)
     padded[:n] = data
     lanesT = padded.reshape(n_blocks, block).T
     rem = np.minimum(n - np.arange(n_blocks) * block, block).astype(np.int32)
-    host_outs, _ = E._fse_enc([Stream(data, SType.SERIAL, 1)], {})
+    host_outs, _ = E._fse_enc(
+        [Stream(data, SType.SERIAL, 1)], {"table_log": table_log}
+    )
     vals, goffs, state, bitpos, byte_off = ops.fse_encode(
         jnp.asarray(lanesT),
         jnp.asarray(rem),
-        jnp.asarray(nb0t.astype(np.int32)),
-        jnp.asarray(thrt.astype(np.int32)),
-        jnp.asarray(st0t.astype(np.int32)),
-        jnp.asarray(norm.astype(np.int32)),
-        jnp.asarray(enc_table.reshape(-1)),
-        width,
-        total,
+        jnp.asarray(tabs.nb0),
+        jnp.asarray(tabs.thr),
+        jnp.asarray(tabs.st0),
+        jnp.asarray(tabs.delta),
+        jnp.asarray(tabs.state_table),
+        use_pallas=use_pallas,
     )
     tb = int(byte_off[-1])
     stream = np.asarray(
@@ -323,6 +345,79 @@ def test_fse_encode_kernel_matches_host_encoder(n):
     assert meta.tobytes() == host_outs[1].content_bytes()
 
 
+@pytest.mark.parametrize(
+    "kind,table_log",
+    [(k, tl) for k in ("weights", "sq_moments", "one_symbol") for tl in (5, 9, 11, 12)]
+    + [("skewed", tl) for tl in (9, 11, 12)],  # > 32 symbols: no table_log 5
+)
+def test_fse_compact_state_table_is_the_encode_table(kind, table_log):
+    """The device walk's 2^table_log state table holds every symbol's row
+    of the padded host encode table: ``enc_table[s, :norm[s]]`` ==
+    ``state_table[k + delta[s]]`` for k in [norm[s], 2 norm[s])."""
+    from repro.codecs import entropy as E
+
+    data = _exponent_plane(kind, FSE_TAIL)
+    norm = E._normalize_counts(E._hist_u8(data), table_log)
+    tabs = E._fse_tables_cached(norm, table_log)
+    assert tabs.state_table.shape == (1 << table_log,)
+    assert tabs.state_table.dtype == np.int32 and tabs.delta.dtype == np.int32
+    for s in np.nonzero(norm)[0]:
+        k = np.arange(norm[s], 2 * norm[s])
+        np.testing.assert_array_equal(
+            tabs.state_table[k + tabs.delta[s]], tabs.enc_table[s, : norm[s]]
+        )
+        assert tabs.st0[s] == tabs.state_table[norm[s] + tabs.delta[s]]
+    np.testing.assert_array_equal(
+        np.sort(tabs.state_table), np.arange(1 << table_log)
+    )
+
+
+def test_fse_device_info_counts_device_encodes():
+    """``fse_device_info()`` counts each device fse encode: one call, its
+    symbols, and its lanes x 1024 lane-steps; a stream under the device
+    window stays on the host and counts nothing."""
+    from repro.codecs.entropy_device import fse_device_info
+    from repro.core import compress, decompress, pipeline, serial
+
+    big, small = _exponent_plane("weights", FSE_TAIL), _skewed(500)
+    before = fse_device_info()
+    for data in (big, small):
+        frame = compress(pipeline("fse"), serial(data.tobytes()), backend="device")
+        assert decompress(frame)[0].content_bytes() == data.tobytes()
+    after = fse_device_info()
+    grew = {k: after[k] - before[k] for k in after}
+    assert grew == {"calls": 1, "symbols": FSE_TAIL, "lane_steps": 4 * 1024}
+
+
+def test_fse_device_info_loses_no_update_under_threads():
+    """Session pools encode on many threads: every count lands."""
+    import sys
+    import threading
+
+    from repro.codecs import entropy_device as ED
+
+    before = ED.fse_device_info()
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [
+            threading.Thread(
+                target=lambda: [ED._count_fse(3, 1024) for _ in range(2000)]
+            )
+            for _ in range(32)
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    after = ED.fse_device_info()
+    grew = {k: after[k] - before[k] for k in after}
+    assert grew == {"calls": 64000, "symbols": 192000, "lane_steps": 65536000}
+
+
 @pytest.mark.parametrize("n", [1, 100, 1025, 50000])
 def test_fse_decode_kernel_roundtrip(n):
     """Device forward walk over host-encoded lanes recovers the input."""
@@ -330,7 +425,7 @@ def test_fse_decode_kernel_roundtrip(n):
     from repro.core.message import Stream, SType
 
     table_log = 11
-    data, norm, (dec_sym, dec_nb, dec_base, _enc) = _fse_fixture(n, table_log)
+    data, norm, (dec_sym, dec_nb, dec_base, *_enc) = _fse_fixture(n, table_log)
     host_outs, _ = E._fse_enc([Stream(data, SType.SERIAL, 1)], {})
     meta = np.frombuffer(host_outs[1].content_bytes(), np.uint32)
     bitlen = meta[0::2].astype(np.int64)

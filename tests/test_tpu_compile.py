@@ -67,13 +67,33 @@ TABLE_U32 = ((256,), jnp.uint32)
 TABLE_I32 = ((256,), jnp.int32)
 
 
+def _compile_fse_encode(one_chip, ops, case):
+    """The tANS encode walk of one 4 MiB u8 chunk's 1024-symbol lane blocks
+    (FSE_BLOCK_LOG = 10) on the compact 2^table_log state table; the
+    ``lanes512`` case is a 512 Ki-symbol plane (a bf16 attention leaf)."""
+    lanes = (N >> 13) if case.endswith("lanes512") else (N >> 10)
+    table_log = 12 if case.endswith("tl12") else 11
+    return _compile(
+        one_chip,
+        ops.fse_encode,
+        ((1024, lanes), jnp.uint8),
+        ((lanes,), jnp.int32),
+        TABLE_I32,
+        TABLE_I32,
+        TABLE_I32,
+        TABLE_I32,
+        ((1 << table_log,), jnp.int32),
+    )
+
+
 # ------------------------------------------------------------ Pallas kernels
 @pytest.mark.parametrize(
     "case",
     ["delta_encode", "float_split", "float_split_bf16", "float_split_f16", "huffman_map"]
     + [f"bitpack{b}" for b in (1, 2, 4, 8, 16)]
     + [f"fused_delta_bitpack{b}" for b in (1, 2, 4, 8, 16)]
-    + [f"byteshuffle{w}" for w in (1, 4, 8)],
+    + [f"byteshuffle{w}" for w in (1, 4, 8)]
+    + ["fse_encode", "fse_encode_tl12", "fse_encode_lanes512"],
 )
 def test_pallas_kernel_compiles_for_v5e(one_chip, tpu_ops, case):
     ops = tpu_ops
@@ -90,6 +110,8 @@ def test_pallas_kernel_compiles_for_v5e(one_chip, tpu_ops, case):
     elif case.startswith("bitpack"):
         bits = int(case[len("bitpack"):])
         text = _compile(one_chip, lambda x: ops.bitpack(x, bits), U32)
+    elif case.startswith("fse_encode"):
+        text = _compile_fse_encode(one_chip, ops, case)
     elif case.startswith("fused_delta_bitpack"):
         bits = int(case[len("fused_delta_bitpack"):])
         text = _compile(one_chip, lambda x: ops.fused_delta_bitpack(x, bits), U32)
@@ -100,30 +122,16 @@ def test_pallas_kernel_compiles_for_v5e(one_chip, tpu_ops, case):
 
 
 # ------------------------------------------------------------ plain XLA glue
-@pytest.mark.parametrize("case", ["histogram_exact", "pack_bits", "fse_encode"])
+@pytest.mark.parametrize("case", ["histogram_exact", "pack_bits"])
 def test_xla_glue_compiles_for_v5e(one_chip, tpu_ops, case):
     ops = tpu_ops
     if case == "histogram_exact":
         _compile(one_chip, ops.histogram_exact, U8)
-    elif case == "pack_bits":
+    else:
         # 15-bit codes at most: a bucketed 8 MiB capacity covers 4 Mi symbols
         _compile(
             one_chip,
             lambda v, o: ops.pack_bits(v, o, 8 << 20),
             U32,
             ((N,), jnp.int32),
-        )
-    else:
-        lanes = N >> 10  # 1024-symbol lane blocks (FSE_BLOCK_LOG = 10)
-        width, total = 512, 1 << 11
-        _compile(
-            one_chip,
-            lambda *a: ops.fse_encode(*a, width, total),
-            ((1024, lanes), jnp.uint8),
-            ((lanes,), jnp.int32),
-            TABLE_I32,
-            TABLE_I32,
-            TABLE_I32,
-            TABLE_I32,
-            ((256 * width,), jnp.int32),
         )
