@@ -9,13 +9,13 @@ JAX could not reach it).  In order:
 1. Device check: exits non-zero before any work unless JAX's first device is
    a TPU.
 2. Session phase: seeded 64 MiB inputs through ``CompressorSession(...,
-   backend="device")``: sorted u32 offsets under delta+bitpack (fused on the
-   device) and delta+transpose+huffman, small-range u32 under bitpack, a
-   bf16-rounded float32 checkpoint tensor under the ``float32`` profile and
-   under an explicit float_split -> transpose_split -> fse/huffman plan, and
-   numeric(4) records under transpose.  Then one 256 MiB input in 4 MiB
-   chunks through the same delta+transpose+huffman session (thread pool +
-   prefetch).
+   backend="device")``: sorted u32 offsets under delta+bitpack, under
+   fused_delta_bitpack and under delta+transpose+huffman, small-range u32
+   under bitpack, a bf16-rounded float32 checkpoint tensor under the
+   ``float32`` profile and under an explicit float_split -> transpose_split
+   -> fse/huffman plan, and numeric(4) records under transpose.  Then one
+   256 MiB input in 4 MiB chunks through the same delta+transpose+huffman
+   session (thread pool + prefetch).
    Every frame must equal the ``backend="host"`` frame byte for byte and
    decode to the input; every device twin must have encoded at least one
    node, and no node expected on the device may have run on the host.
@@ -111,29 +111,27 @@ def session_phase(seed: int, size: int, chunked_size: int, chunk: int) -> dict:
     rng = np.random.default_rng(seed)
     offsets = offsets_u32(rng, size)
     ckpt = bf16_checkpoint_f32(rng, size)
-    # the device backend rewrites delta -> bitpack into one fused node, so
-    # that plan's host reference is the fused program itself
-    fused = pipeline("fused_delta_bitpack")
     cases = [
-        ("offsets/delta+bitpack", pipeline("delta", "bitpack"), fused, offsets,
-         {"fused_delta_bitpack"}),
         ("offsets/delta+transpose+huffman",
-         pipeline("delta", "transpose", "huffman"), None, offsets,
+         pipeline("delta", "transpose", "huffman"), offsets,
          {"delta", "transpose", "huffman"}),
-        ("small_range/bitpack", pipeline("bitpack"), None,
+        ("offsets/delta+bitpack", pipeline("delta", "bitpack"), offsets,
+         {"delta", "bitpack"}),
+        ("offsets/fused_delta_bitpack", pipeline("fused_delta_bitpack"), offsets,
+         {"fused_delta_bitpack"}),
+        ("small_range/bitpack", pipeline("bitpack"),
          small_range_u32(rng, size), {"bitpack"}),
-        ("bf16_f32/float32_profile", float32_profile(), None, ckpt,
-         {"float_split"}),
-        ("bf16_f32/float_planes", float_planes_plan(), None, ckpt,
+        ("bf16_f32/float32_profile", float32_profile(), ckpt, {"float_split"}),
+        ("bf16_f32/float_planes", float_planes_plan(), ckpt,
          {"float_split", "transpose_split", "fse", "huffman"}),
-        ("records/transpose", pipeline("transpose"), None,
+        ("records/transpose", pipeline("transpose"),
          records_u32(rng, size), {"transpose"}),
     ]
     totals: dict = {}
     sessions = []
-    for name, plan, host_plan, arr, expect in cases:
+    for name, plan, arr, expect in cases:
         stream = numeric(arr)
-        with CompressorSession(host_plan or plan, backend="host") as host:
+        with CompressorSession(plan, backend="host") as host:
             t0 = time.perf_counter()
             want = host.compress(stream)
             t_host = time.perf_counter() - t0
@@ -162,8 +160,8 @@ def session_phase(seed: int, size: int, chunked_size: int, chunk: int) -> dict:
 
     # the chunked run reuses the delta+transpose+huffman session (every node
     # of each chunk stays on the device): thread pool + prefetch
-    name, plan, _, _, expect = cases[1]
-    dev = sessions[1]
+    name, plan, _, expect = cases[0]
+    dev = sessions[0]
     before = dev.stats["chunks"]
     big = numeric(offsets_u32(rng, chunked_size))
     with CompressorSession(plan, backend="host") as host:
@@ -205,18 +203,17 @@ def service_phase(seed: int, sizes_mib, chunk: int) -> None:
     rng = np.random.default_rng(seed + 1)
     registry = PlanRegistry()
     u32 = ("interpret_numeric", {"width": 4})
-    plans = {  # plan id -> (served plan, its offline host reference)
-        "offsets": (pipeline(u32, "delta", "bitpack"),
-                    pipeline(u32, "fused_delta_bitpack")),
-        "offsets_huffman": (pipeline(u32, "delta", "transpose", "huffman"),) * 2,
+    plans = {
+        "offsets": pipeline(u32, "delta", "bitpack"),
+        "offsets_huffman": pipeline(u32, "delta", "transpose", "huffman"),
     }
-    for plan_id, (plan, _) in plans.items():
+    for plan_id, plan in plans.items():
         registry.register_compressor(Compressor(plan), plan_id)
     payloads = []
     for i, mib in enumerate(sizes_mib):
         plan_id = "offsets" if i % 2 == 0 else "offsets_huffman"
         data = offsets_u32(rng, mib * MIB).tobytes()
-        want = Compressor(plans[plan_id][1]).compress(serial(data), chunk_bytes=chunk)
+        want = Compressor(plans[plan_id]).compress(serial(data), chunk_bytes=chunk)
         payloads.append((plan_id, data, want))
     with tempfile.TemporaryDirectory(prefix="ozs") as tmp:
         server = CompressionServer(

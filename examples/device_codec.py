@@ -45,7 +45,6 @@ restored = ops.fused_delta_bitpack_decode(packed, bits, offs.shape[0])
 assert bool(jnp.all(restored == offs))
 print(f"fused delta+bitpack: {offs.nbytes} B -> {packed.nbytes} B "
       f"({offs.nbytes/packed.nbytes:.1f}x), single-pass, bit-exact")
-print("HBM traffic model (EXPERIMENTS.md §Perf/K1): 13 B/elt unfused -> 5 B/elt fused (2.6x)")
 
 # ---- byte-plane shuffle for struct data ------------------------------------
 recs = jnp.asarray(rng.integers(0, 256, (1 << 14, 4)), jnp.uint8)
@@ -56,7 +55,8 @@ print("\nall kernels ran under jit (Mosaic on TPU; jnp oracles elsewhere)")
 
 # ---- the engine-level device backend ---------------------------------------
 # The same kernels drive real compression: resolve once, execute per call
-# with backend="device", fusing adjacent delta+bitpack into one kernel pass.
+# with backend="device".  Each plan node runs as one wire node on either
+# backend, so the device writes the host's frame byte for byte.
 from repro.core import compress, decompress, numeric, pipeline
 from repro.core.wire import is_container, read_frame
 
@@ -64,12 +64,13 @@ offsets = numeric(np.cumsum(rng.integers(0, 200, 1 << 16)).astype(np.uint32))
 plan = pipeline("delta", "bitpack")
 frame_host = compress(plan, offsets, backend="host")
 frame_dev = compress(plan, offsets, backend="device")
+assert frame_dev == frame_host, "device frame must equal the host frame"
 _, _, nodes, _ = read_frame(frame_dev)
 assert decompress(frame_dev)[0].content_bytes() == offsets.content_bytes()
-print(f"\nengine backend=device: delta+bitpack fused into "
-      f"{len(nodes)} wire node (codec id {nodes[0].codec_id}), "
-      f"{offsets.nbytes} B -> {len(frame_dev)} B, universal decode bit-exact")
-assert len(frame_dev) <= len(frame_host)
+print(f"\nengine backend=device: delta+bitpack as {len(nodes)} wire nodes "
+      f"(codec ids {[n.codec_id for n in nodes]}), {offsets.nbytes} B -> "
+      f"{len(frame_dev)} B, byte-identical to the host frame, "
+      f"universal decode bit-exact")
 
 chunked = compress(plan, offsets, chunk_bytes=1 << 16, backend="device")
 assert is_container(chunked)

@@ -10,15 +10,35 @@ import zlib
 import lzma
 from pathlib import Path
 
-sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "benchmarks"))
+sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
-import numpy as np
+import numpy as np  # noqa: E402
 
-from repro.codecs import sao_profile
-from repro.core import Compressor
+from repro.codecs import sao_profile  # noqa: E402
+from repro.codecs.profiles import SAO_HEADER_BYTES  # noqa: E402
+from repro.core import Compressor  # noqa: E402
 
-sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-from benchmarks.datasets import make_sao  # noqa: E402
+
+def make_sao(n_records: int, seed: int = 0) -> bytes:
+    """Synthetic star catalogue with the SAO record layout: sorted
+    right-ascension f64, bounded declination f64, low-cardinality
+    spectral/magnitude/motion fields (paper §IV)."""
+    rng = np.random.default_rng(seed)
+    rec = np.zeros(
+        n_records,
+        dtype=[("sra", "<f8"), ("sdec", "<f8"), ("is", "<u2"), ("mag", "<i2"),
+               ("xrpm", "<f4"), ("xdpm", "<f4")],
+    )
+    zipf = np.arange(1, 65, dtype=np.float64) ** -1.3
+    rec["sra"] = np.sort(rng.uniform(0, 2 * np.pi, n_records))
+    rec["sdec"] = rng.uniform(-np.pi / 2, np.pi / 2, n_records)
+    rec["is"] = rng.choice(64, n_records, p=zipf / zipf.sum())
+    rec["mag"] = rng.choice(np.arange(-149, 1450, 10, dtype=np.int16), n_records)
+    for f, k in (("xrpm", 997), ("xdpm", 1009)):
+        grid = np.round(np.linspace(-0.5, 0.5, k), 5).astype(np.float32)
+        rec[f] = rng.choice(grid, n_records)
+    return b"\x00" * SAO_HEADER_BYTES + rec.tobytes()
+
 
 data = make_sao(50_000)
 print(f"SAO: {len(data)} bytes ({len(data)/(1<<20):.2f} MiB), 28-byte star records")

@@ -17,11 +17,34 @@ from pathlib import Path
 
 import numpy as np
 
-sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
-from benchmarks.datasets import make_tlc_columns  # noqa: E402
-from repro.core import Compressor  # noqa: E402
+from repro.core import Compressor, numeric  # noqa: E402
 from repro.training import MultiStreamFrontend, train  # noqa: E402
+
+
+def make_tlc_columns(n_rows: int, seed: int):
+    """Taxi trips: near-sorted pickup times, quantized fares/distances,
+    low-cardinality location/vendor/passenger fields."""
+    rng = np.random.default_rng(seed)
+    zipf = np.arange(1, 266, dtype=np.float64) ** -1.1
+    zipf /= zipf.sum()
+    pickup = np.sort(
+        1_735_000_000 + (rng.pareto(2.0, n_rows) * 5e6).astype(np.int64) % 7_800_000
+    )
+    dropoff = pickup + rng.lognormal(6.2, 0.8, n_rows).astype(np.int32)
+    dist = np.round(rng.lognormal(0.8, 0.9, n_rows), 2)
+    fare = np.round(3.0 + dist * 2.5 + rng.normal(0, 1, n_rows).clip(0), 2)
+    tip = np.round(fare * rng.choice([0, 0.1, 0.15, 0.2, 0.25], n_rows), 2)
+    loc_p = rng.choice(265, n_rows, p=zipf).astype(np.uint16)
+    loc_d = rng.choice(265, n_rows, p=zipf).astype(np.uint16)
+    vendor = rng.choice(3, n_rows).astype(np.uint8)
+    passengers = rng.choice([1, 1, 1, 2, 2, 3, 5], n_rows).astype(np.uint8)
+    return [
+        numeric(c)
+        for c in (pickup, dropoff, dist, fare, tip, loc_p, loc_d, vendor, passengers)
+    ]
+
 
 # taxi-trip-like columnar data (paper's TLC dataset family)
 train_cols = make_tlc_columns(20_000, seed=1)

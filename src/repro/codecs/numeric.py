@@ -527,9 +527,9 @@ def _bits_for_need(need: int, explicit_bits: int) -> Optional[int]:
 
     Dynamic selection only fuses when the width is *exact* (need is itself a
     32-divisor <= 16): rounding 3 bits up to 4 would inflate the packed
-    stream vs separate delta+bitpack, and the device backend guarantees
-    frames never larger than the host's.  Explicit widths are the caller's
-    ratio decision and are honored as long as the kernel can express them.
+    stream vs separate delta+bitpack, which the executor runs in place of a
+    refused step.  Explicit widths are the caller's ratio decision and are
+    honored as long as the kernel can express them.
     """
     if explicit_bits:
         if explicit_bits not in FUSED_BITS_CHOICES or need > explicit_bits:
@@ -543,19 +543,6 @@ def _bits_for_need(need: int, explicit_bits: int) -> Optional[int]:
 def _bits_for_delta(d: np.ndarray, explicit_bits: int) -> Optional[int]:
     maxd = int(d.max()) if d.size else 0
     return _bits_for_need(max(maxd.bit_length(), 1), explicit_bits)
-
-
-def fused_bits_for(s: Stream, explicit_bits: int = 0) -> Optional[int]:
-    """Packing width if the fused kernel's lossless precondition holds.
-
-    Returns None when the node must run as separate delta+bitpack: non-numeric
-    or u64 input, a wrapped u32 delta that does not fit, an explicit width the
-    32-bit-word kernel cannot express, or (dynamic case) a width where fusion
-    stops paying for itself.
-    """
-    if s.stype != SType.NUMERIC or s.width not in (1, 2, 4):
-        return None
-    return _bits_for_delta(_u32_delta(s), explicit_bits)
 
 
 def _fused_enc(streams, params):
@@ -673,7 +660,7 @@ register_backend_codec("device", "bitpack", _bitpack_enc_device, _bitpack_applie
 
 def _fused_applies_device(streams, params):
     # static checks only; the encoder validates the data-dependent lossless
-    # precondition itself and raises a refusal (the executor's lowering signal)
+    # precondition itself and refuses, as the host encoder does
     explicit = int(params.get("bits", 0))
     return _dev_ready(streams[0]) and (
         not explicit or explicit in FUSED_BITS_CHOICES

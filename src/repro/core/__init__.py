@@ -36,7 +36,6 @@ from .engine import (  # noqa: F401
     decompress,
     decompress_bytes,
     execute,
-    fuse_resolved,
     resolve,
     resolve_cache_clear,
     resolve_cache_info,

@@ -11,10 +11,9 @@ Compression is split into:
   * **execute** — ``execute(resolved, streams, backend=...) -> frame``: runs
     the codec encoders over concrete data.  Encoders dispatch per *backend*:
     ``host`` is the numpy codec suite; ``device`` routes numeric transform
-    nodes through the jit'd Pallas wrappers in ``repro.kernels.ops`` (bit-exact
-    with host) and applies a graph-rewrite pass fusing adjacent
-    ``delta``+``bitpack`` nodes into the single-pass ``fused_delta_bitpack``
-    kernel when its lossless precondition holds.
+    nodes through the jit'd Pallas wrappers in ``repro.kernels.ops``.  Both
+    backends execute the same resolved steps and write the same frame, byte
+    for byte.
 
 ``compress()`` composes the two and optionally chunks large inputs
 (``chunk_bytes=N``) into independently compressed pieces executed on a thread
@@ -40,6 +39,7 @@ including both single- and multi-chunk frames.
 """
 from __future__ import annotations
 
+import dataclasses
 import io
 import os
 import threading
@@ -92,7 +92,6 @@ __all__ = [
     "stream_meta",
     "resolve",
     "execute",
-    "fuse_resolved",
     "resolve_cache_info",
     "resolve_cache_clear",
     "set_resolve_check",
@@ -104,9 +103,6 @@ __all__ = [
     "DecompressorSession",
     "SessionPool",
 ]
-
-FUSED_NAME = "fused_delta_bitpack"
-
 
 @dataclass
 class CompressionCtx:
@@ -173,9 +169,9 @@ class ResolvedStep:
     """One codec invocation in a resolved program.
 
     Edge ids are *resolved-plan* ids: inputs ``0..n_inputs-1`` are the graph
-    inputs, each step's outputs take the next consecutive ids.  The execute
-    phase maps these to runtime edge ids (they diverge only when a fused step
-    falls back to its constituent codecs).
+    inputs, each step's outputs take the next consecutive ids.  Each step
+    runs as one wire node, so these are also the frame's edge ids, unless a
+    ``fused_delta_bitpack`` step refuses its data (:class:`_Executor`).
     """
 
     name: str
@@ -199,7 +195,6 @@ class ResolvedPlan:
     format_version: int
     level: int
     name: str = ""
-    fused: bool = False  # True once the delta+bitpack rewrite has run
 
     @property
     def n_edges(self) -> int:
@@ -244,10 +239,10 @@ class _Resolver:
                 f"plan {plan.name!r} wants {plan.n_inputs} inputs,"
                 f" got {len(input_edge_ids)}"
             )
-        emap: Dict[int, int] = {i: eid for i, eid in enumerate(input_edge_ids)}
+        edge_of: Dict[int, int] = {i: eid for i, eid in enumerate(input_edge_ids)}
         next_plan_edge = plan.n_inputs
         for node in plan.nodes:
-            in_ids = [emap[e] for e in node.inputs]
+            in_ids = [edge_of[e] for e in node.inputs]
             if node.kind == KIND_CODEC:
                 spec = _checked_codec(node.name, self.ctx.format_version)
                 ins = [self.consume(e) for e in in_ids]
@@ -264,7 +259,7 @@ class _Resolver:
                     )
                 )
                 for k, oid in enumerate(out_ids):
-                    emap[next_plan_edge + k] = oid
+                    edge_of[next_plan_edge + k] = oid
                 next_plan_edge += node.n_out
             else:  # selector: expand recursively
                 sel = get_selector(node.name)
@@ -477,93 +472,36 @@ def _all_metas(inputs) -> bool:
     )
 
 
-# ------------------------------------------------------------- fusion pass
-def fuse_resolved(resolved: ResolvedPlan) -> ResolvedPlan:
-    """Graph rewrite: adjacent ``delta`` -> ``bitpack`` chains become one
-    ``fused_delta_bitpack`` step (single-pass kernel on the device backend).
-
-    Static preconditions only — the data-dependent lossless precondition
-    (every wrapped u32 delta fits in the packing width) is checked per call by
-    the executor, which lowers the step back to its constituents when it
-    fails.  Gated on the fused codec's ``min_version`` (format v4).
-    """
-    from repro.codecs.numeric import FUSED_BITS_CHOICES  # lazy: avoids cycle
-
-    fused_spec = get_codec(FUSED_NAME)
-    if resolved.fused or resolved.format_version < fused_spec.min_version:
-        return resolved
-    steps = resolved.steps
-    # bitpack step index -> its delta producer index, for fusable pairs
-    producer_of: Dict[int, int] = {}
-    out_edge_of: Dict[int, int] = {}  # step idx -> first output edge id
-    e = resolved.n_inputs
-    for i, s in enumerate(steps):
-        out_edge_of[i] = e
-        e += s.n_out
-    delta_by_out = {
-        out_edge_of[i]: i
-        for i, s in enumerate(steps)
-        if s.name == "delta" and s.n_out == 1 and not s.params
-    }
-    for j, s in enumerate(steps):
-        if s.name != "bitpack" or len(s.inputs) != 1:
-            continue
-        bits = int(s.param_dict().get("bits", 0))
-        if bits and bits not in FUSED_BITS_CHOICES:
-            continue  # packing width the 32-bit-word kernel can't express
-        i = delta_by_out.get(s.inputs[0])
-        if i is not None:
-            producer_of[j] = i
-    if not producer_of:
-        return ResolvedPlan(
-            resolved.n_inputs, steps, resolved.format_version, resolved.level,
-            resolved.name, fused=True,
-        )
-
-    fused_deltas = set(producer_of.values())
-    emap: Dict[int, int] = {i: i for i in range(resolved.n_inputs)}
-    new_steps: List[ResolvedStep] = []
-    next_new = resolved.n_inputs
-    for i, s in enumerate(steps):
-        old_out0 = out_edge_of[i]
-        if i in fused_deltas:
-            continue  # its output edge is interior to the fused pair
-        if i in producer_of:
-            d = steps[producer_of[i]]
-            bits = int(s.param_dict().get("bits", 0))
-            params = (("bits", bits),) if bits else ()
-            new_steps.append(
-                ResolvedStep(
-                    FUSED_NAME,
-                    fused_spec.codec_id,
-                    tuple(emap[e] for e in d.inputs),
-                    1,
-                    params,
-                )
-            )
-        else:
-            new_steps.append(
-                ResolvedStep(
-                    s.name, s.codec_id, tuple(emap[e] for e in s.inputs),
-                    s.n_out, s.params,
-                )
-            )
-        for k in range(s.n_out):
-            emap[old_out0 + k] = next_new
-            next_new += 1
-    return ResolvedPlan(
-        resolved.n_inputs, tuple(new_steps), resolved.format_version,
-        resolved.level, resolved.name, fused=True,
-    )
-
-
 # ------------------------------------------------------------- execute phase
+def _lower_refused_fused(
+    steps: Sequence[ResolvedStep], out_edge: int
+) -> List[ResolvedStep]:
+    """``steps[0]`` is a ``fused_delta_bitpack`` step whose lossless
+    precondition failed on this data: run it as ``delta`` -> ``bitpack``
+    instead, on every backend alike.  Its output edge ``out_edge`` becomes the
+    interior delta edge, so every later edge id shifts up by one."""
+    fused = steps[0]
+    bits = int(fused.param_dict().get("bits", 0))
+    return [
+        ResolvedStep("delta", get_codec("delta").codec_id, fused.inputs, 1),
+        ResolvedStep(
+            "bitpack", get_codec("bitpack").codec_id, (out_edge,), 1,
+            (("bits", bits),) if bits else (),
+        ),
+    ] + [
+        dataclasses.replace(s, inputs=tuple(e + (e >= out_edge) for e in s.inputs))
+        for s in steps[1:]
+    ]
+
+
 class _Executor:
     """Runs a ResolvedPlan over concrete streams with backend dispatch.
 
-    Maintains its own runtime edge numbering (``emap``: resolved edge id ->
-    runtime edge id) because a fused step may lower to two wire nodes with an
-    interior edge the resolved plan never saw.
+    Every resolved step runs as exactly one wire node whatever the backend,
+    so runtime edge ids are the resolved edge ids: graph inputs first, then
+    each node's outputs in execution order.  The one data-dependent rewrite
+    is backend-independent: a ``fused_delta_bitpack`` step that refuses its
+    data runs as ``delta`` -> ``bitpack`` (:func:`_lower_refused_fused`).
 
     ``trace`` (optional) collects one ``(codec_name, input_bytes)`` pair per
     executed codec, in execution order — the raw material for the trainer's
@@ -585,19 +523,9 @@ class _Executor:
         self.backend = backend
         self.trace = trace
         self.routes = routes
-        self.edges: List[Stream] = []
-        self.consumed: List[bool] = []
+        self.edges: List[Stream] = list(streams)
+        self.consumed: List[bool] = [False] * len(self.edges)
         self.nodes: List[ResolvedNode] = []
-        self.emap: Dict[int, int] = {}
-        for i, s in enumerate(streams):
-            self.edges.append(s)
-            self.consumed.append(False)
-            self.emap[i] = i
-
-    def _new_edge(self, s: Stream) -> int:
-        self.edges.append(s)
-        self.consumed.append(False)
-        return len(self.edges) - 1
 
     def _consume(self, e: int) -> Stream:
         if self.consumed[e]:
@@ -605,35 +533,37 @@ class _Executor:
         self.consumed[e] = True
         return self.edges[e]
 
-    def _run_codec(self, name: str, params: dict, rt_ins: List[int]) -> List[int]:
-        spec = _checked_codec(name, self.resolved.format_version)
-        ins = [self._consume(e) for e in rt_ins]
-        if self.trace is not None:
-            self.trace.append((name, sum(s.nbytes for s in ins)))
-        outs, header, by = run_encode_via(spec, self.backend, ins, params)
-        if self.routes is not None:
-            self.routes[(by, name)] += 1
-        out_ids = [self._new_edge(o) for o in outs]
-        self.nodes.append(ResolvedNode(spec.codec_id, tuple(rt_ins), len(outs), header))
-        return out_ids
-
     def run(self) -> bytes:
-        next_resolved_edge = self.resolved.n_inputs
-        for step in self.resolved.steps:
-            rt_ins = [self.emap[e] for e in step.inputs]
-            if step.name == FUSED_NAME:
-                out_ids = self._run_fused(step, rt_ins)
-            else:
-                outs_expected = step.n_out
-                out_ids = self._run_codec(step.name, step.param_dict(), rt_ins)
-                if len(out_ids) != outs_expected:
-                    raise AssertionError(
-                        f"codec {step.name}: resolved n_out={outs_expected},"
-                        f" produced {len(out_ids)}"
-                    )
-            for k, oid in enumerate(out_ids):
-                self.emap[next_resolved_edge + k] = oid
-            next_resolved_edge += step.n_out
+        steps = list(self.resolved.steps)
+        i = 0
+        while i < len(steps):
+            step = steps[i]
+            spec = _checked_codec(step.name, self.resolved.format_version)
+            ins = [self._consume(e) for e in step.inputs]
+            try:
+                outs, header, by = run_encode_via(
+                    spec, self.backend, ins, step.param_dict()
+                )
+            except ValueError:
+                if step.name != "fused_delta_bitpack":
+                    raise
+                for e in step.inputs:
+                    self.consumed[e] = False
+                steps[i:] = _lower_refused_fused(steps[i:], len(self.edges))
+                continue
+            if len(outs) != step.n_out:
+                raise AssertionError(
+                    f"codec {step.name}: resolved n_out={step.n_out},"
+                    f" produced {len(outs)}"
+                )
+            if self.trace is not None:
+                self.trace.append((step.name, sum(s.nbytes for s in ins)))
+            if self.routes is not None:
+                self.routes[(by, step.name)] += 1
+            self.edges.extend(outs)
+            self.consumed.extend([False] * len(outs))
+            self.nodes.append(ResolvedNode(spec.codec_id, step.inputs, len(outs), header))
+            i += 1
         stored = [
             (eid, self.edges[eid])
             for eid in range(len(self.edges))
@@ -643,50 +573,21 @@ class _Executor:
             self.resolved.format_version, self.resolved.n_inputs, self.nodes, stored
         )
 
-    def _run_fused(self, step: ResolvedStep, rt_ins: List[int]) -> List[int]:
-        """Run the fused kernel when lossless, else lower to delta+bitpack.
-
-        The encoder itself validates the lossless precondition (one pass) and
-        raises a ValueError refusal when it fails — which is the lowering
-        signal.  The input edge is only consumed once the attempt succeeds.
-        """
-        spec = _checked_codec(FUSED_NAME, self.resolved.format_version)
-        params = step.param_dict()
-        s = self.edges[rt_ins[0]]  # peek: do not consume before we commit
-        try:
-            outs, header, by = run_encode_via(spec, self.backend, [s], params)
-        except ValueError:
-            explicit = int(params.get("bits", 0))
-            d_out = self._run_codec("delta", {}, rt_ins)
-            return self._run_codec(
-                "bitpack", {"bits": explicit} if explicit else {}, d_out
-            )
-        self._consume(rt_ins[0])
-        if self.trace is not None:
-            self.trace.append((FUSED_NAME, s.nbytes))
-        if self.routes is not None:
-            self.routes[(by, FUSED_NAME)] += 1
-        out_ids = [self._new_edge(o) for o in outs]
-        self.nodes.append(ResolvedNode(spec.codec_id, tuple(rt_ins), len(outs), header))
-        return out_ids
-
 
 def execute(
     resolved: ResolvedPlan,
     inputs: Union[Stream, bytes, Sequence[Stream]],
     *,
     backend: str = "host",
-    fuse: Optional[bool] = None,
     scratch: Optional[ExecScratch] = None,
     trace: Optional[List[Tuple[str, int]]] = None,
     routes: Optional[Counter] = None,
 ) -> bytes:
     """Phase 2: run a resolved program over concrete streams -> wire frame.
 
-    ``fuse`` defaults to True on the device backend (where the fused kernel
-    lives); pass an explicit bool to override either way.  ``scratch`` scopes
-    per-call coder-table caching; the chunked ``compress()`` path passes one
-    shared scratch to every pool worker so read-only tables are built once.
+    ``scratch`` scopes per-call coder-table caching; the chunked
+    ``compress()`` path passes one shared scratch to every pool worker so
+    read-only tables are built once.
     ``trace`` (a caller-owned list) collects ``(codec_name, input_bytes)`` per
     executed step and ``routes`` (a caller-owned Counter) the backend that
     encoded each node — see :class:`_Executor`.
@@ -700,10 +601,6 @@ def execute(
         raise ValueError(
             f"unknown backend {backend!r}; available: {available_backends()}"
         )
-    if fuse is None:
-        fuse = backend != "host"
-    if fuse:
-        resolved = fuse_resolved(resolved)
     if scratch is None:
         return _Executor(resolved, streams, backend, trace, routes).run()
     with scratch.activate():
@@ -783,7 +680,6 @@ class _SessionBase:
         table_cache_size: int,
         pool_name: str,
         scratch: Optional[ExecScratch] = None,
-        prefetch: bool = True,
     ):
         self.n_workers = n_workers
         # a caller-provided scratch lets many sessions share one coder-table
@@ -794,7 +690,6 @@ class _SessionBase:
         self._draw_pool: Optional[ThreadPoolExecutor] = None
         self._pool_lock = threading.Lock()
         self._pool_name = pool_name
-        self.prefetch = prefetch
         self._stats_lock = threading.Lock()
         self.stats: Dict[str, float] = {
             "calls": 0,
@@ -854,45 +749,37 @@ class _SessionBase:
         alive.  ``head`` prepends already-drawn items without re-consuming the
         iterator.
 
-        Double-buffered: with :attr:`prefetch` on, the next item is drawn
-        from the source *on the pool* while encodes are in flight, so chunk
-        N's encode overlaps chunk N+1's host stage (split, file read,
-        host->device transfer for a lazy source).  At most one draw is in
-        flight, preserving the source's single-consumer contract; the
+        Double-buffered: the next item is drawn from the source on a
+        dedicated thread while encodes are in flight, so chunk N's encode
+        overlaps chunk N+1's host stage (split, file read, host->device
+        transfer for a lazy source).  At most one draw is in flight,
+        preserving the source's single-consumer contract; the
         prefetch_hits / draw_wait_s counters in :attr:`stats` report how much
         of the host stage the overlap actually hid."""
         pool = self._pool_get()
         window = self.window
         it = iter(items)
         pending: "deque" = deque(pool.submit(fn, x) for x in (head or []))
-        drawer = self._draw_pool_get() if self.prefetch else None
-        draw = drawer.submit(next, it, _DRAW_END) if drawer is not None else None
+        drawer = self._draw_pool_get()
+        draw = drawer.submit(next, it, _DRAW_END)
         exhausted = False
         try:
             while pending or not exhausted:
                 while not exhausted and len(pending) < window:
-                    if draw is not None:
-                        hidden = bool(pending) and draw.done()
-                        t0 = time.perf_counter()
-                        item = draw.result()
-                        dt = time.perf_counter() - t0
-                        if item is _DRAW_END:
-                            exhausted = True
-                            draw = None
-                            break
-                        pending.append(pool.submit(fn, item))
-                        draw = drawer.submit(next, it, _DRAW_END)
-                        with self._stats_lock:
-                            key = "prefetch_hits" if hidden else "prefetch_misses"
-                            self.stats[key] += 1
-                            self.stats["draw_wait_s"] += dt
-                    else:
-                        try:
-                            item = next(it)
-                        except StopIteration:
-                            exhausted = True
-                            break
-                        pending.append(pool.submit(fn, item))
+                    hidden = bool(pending) and draw.done()
+                    t0 = time.perf_counter()
+                    item = draw.result()
+                    dt = time.perf_counter() - t0
+                    if item is _DRAW_END:
+                        exhausted = True
+                        draw = None
+                        break
+                    pending.append(pool.submit(fn, item))
+                    draw = drawer.submit(next, it, _DRAW_END)
+                    with self._stats_lock:
+                        key = "prefetch_hits" if hidden else "prefetch_misses"
+                        self.stats[key] += 1
+                        self.stats["draw_wait_s"] += dt
                 if not pending:
                     break
                 with self._stats_lock:
@@ -960,12 +847,11 @@ class CompressorSession(_SessionBase):
     encode → in-order incremental write* behind a bounded in-flight window, so
     feeding it a lazy chunk iterator (``repro.core.stream_io``) compresses
     arbitrarily large inputs with peak memory ≈ ``window × chunk_bytes``.
-    The window is double-buffered (``prefetch=True``): chunk N+1's host
-    stage — split, file read, host->device transfer — is drawn on the pool
-    while chunk N encodes, and ``stats["prefetch_hits"]`` /
-    ``stats["draw_wait_s"]`` / ``stats["encode_wait_s"]`` report how much of
-    it the overlap hid.  Knobs: ``window`` bounds chunks in flight,
-    ``n_workers`` sizes the pool, ``prefetch`` disables the double buffer.
+    The window is double-buffered: chunk N+1's host stage — split, file
+    read, host->device transfer — is drawn on its own thread while chunk N
+    encodes, and ``stats["prefetch_hits"]`` / ``stats["draw_wait_s"]`` /
+    ``stats["encode_wait_s"]`` report how much of it the overlap hid.  Knobs:
+    ``window`` bounds chunks in flight, ``n_workers`` sizes the pool.
 
     Output is byte-identical to the module-level ``compress()`` with the same
     arguments — sessions change *when* work happens, never the wire format.
@@ -985,12 +871,9 @@ class CompressorSession(_SessionBase):
         use_resolve_cache: bool = True,
         table_cache_size: int = 256,
         scratch: Optional[ExecScratch] = None,
-        prefetch: bool = True,
         failover: Optional[object] = None,
     ):
-        super().__init__(
-            n_workers, window, table_cache_size, "ozl-enc", scratch, prefetch
-        )
+        super().__init__(n_workers, window, table_cache_size, "ozl-enc", scratch)
         self.plan = plan.validate()
         self.ctx = ctx or CompressionCtx()
         check_compress_version(self.ctx.format_version)
@@ -1126,8 +1009,7 @@ class CompressorSession(_SessionBase):
 
         Returns ``(frame, trace, seconds)`` where ``trace`` is the executed
         ``(codec_name, input_bytes)`` list and ``seconds`` the wall-clock
-        resolve+execute time from ``time.perf_counter`` (the clock the
-        benchmarks use).  The frame is byte-identical to
+        resolve+execute time from ``time.perf_counter``.  The frame is byte-identical to
         ``compress(..., chunk_bytes=0)``.  This is the trainer's candidate
         evaluation path: the trace feeds its *deterministic* cost model, the
         timing its reporting.
@@ -1243,11 +1125,8 @@ class DecompressorSession(_SessionBase):
         window: Optional[int] = None,
         table_cache_size: int = 256,
         scratch: Optional[ExecScratch] = None,
-        prefetch: bool = True,
     ):
-        super().__init__(
-            n_workers, window, table_cache_size, "ozl-dec", scratch, prefetch
-        )
+        super().__init__(n_workers, window, table_cache_size, "ozl-dec", scratch)
 
     def _one(self, frame: bytes) -> List[Stream]:
         with self.scratch.activate():

@@ -1,4 +1,4 @@
-"""Synthetic dataset generators for the example drivers and benchmarks.
+"""Synthetic dataset generators for the example and training drivers.
 
 LM corpora are Zipf-distributed token streams with Markov bigram structure
 (so entropy coding AND the LM both have signal); recsys batches follow

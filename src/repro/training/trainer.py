@@ -16,8 +16,8 @@ calibrated per-codec cost model over the executed step trace — a pure
 function of (genome, sample) — never a wall-clock measurement, and variation
 uses per-genome RNG streams (:func:`~repro.training.nsga2.rng_stream`).  The
 same seed therefore yields byte-identical Pareto plans for any worker count.
-Wall-clock per-candidate timings (``time.perf_counter``, the benchmarks'
-clock path) are still recorded — in ``stats`` — for reporting.
+Wall-clock per-candidate timings (``time.perf_counter``) are still
+recorded — in ``stats`` — for reporting.
 """
 from __future__ import annotations
 
@@ -321,8 +321,8 @@ def _seed_genomes(sig: Tuple[int, int]) -> List[Optional[GNode]]:
 
 
 # ----------------------------------------------------- deterministic cost
-# Per-codec encode cost in ns/input-byte, loosely calibrated against the
-# host measurements in results/BENCH_codecs.json (and stdlib backend docs).
+# Per-codec encode cost in ns/input-byte, loosely calibrated against host
+# encode timings of each codec (and stdlib backend docs).
 # This is the NSGA-II *speed objective*: a pure function of the executed
 # step trace, so identically seeded training runs rank candidates
 # identically on any machine and worker count.  Absolute accuracy matters

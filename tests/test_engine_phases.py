@@ -1,5 +1,5 @@
-"""The two-phase engine: resolve caching, backend dispatch + bit-exactness,
-the delta+bitpack fusion rewrite, and multi-chunk container frames."""
+"""The two-phase engine: resolve caching, backend dispatch + bit-exactness
+(one frame per plan on every backend), and multi-chunk container frames."""
 import numpy as np
 import pytest
 
@@ -13,7 +13,6 @@ from repro.core import (
     decompress,
     decompress_bytes,
     execute,
-    fuse_resolved,
     numeric,
     pipeline,
     resolve,
@@ -149,6 +148,12 @@ def _routed_cases():
     g = GraphBuilder(1)
     g.add("transpose_split", g.input(0), n_out=4)
     tsplit = g.build("tsplit")
+    g = GraphBuilder(1)
+    a, b = g.add("dup", g.input(0))
+    g.add("bitpack", g.add("delta", a))
+    g.add("transpose", b)
+    dup_delta_bitpack = g.build("dup_delta_bitpack")
+    delta_bitpack = pipeline("delta", "bitpack")
     return [
         ("delta_u8", pipeline("delta"), numeric(np.arange(777, dtype=np.uint8))),
         ("delta_u16", pipeline("delta"), numeric(np.arange(777, dtype=np.uint16))),
@@ -170,79 +175,59 @@ def _routed_cases():
         ("float_split_f64_fallback", pipeline(("float_split", {"fmt": 3})), numeric(rng.integers(0, 1 << 60, 100).astype(np.uint64))),
         ("fused", pipeline("fused_delta_bitpack"), sorted_u32()),
         ("empty", pipeline("delta"), numeric(np.zeros(0, dtype=np.uint32))),
+        # delta -> bitpack runs as two nodes (codecs 3 and 6) on every backend
+        ("delta_bitpack_sorted_u32", delta_bitpack, sorted_u32()),
+        (
+            "delta_bitpack_wide_wrapped",
+            delta_bitpack,
+            numeric(rng.integers(0, 1 << 31, 2000).astype(np.uint32)),
+        ),
+        (
+            "delta_bitpack_3bit",
+            delta_bitpack,
+            numeric(np.cumsum(rng.integers(0, 8, 2000)).astype(np.uint32)),
+        ),
+        (
+            "delta_bitpack_explicit_8",
+            pipeline("delta", ("bitpack", {"bits": 8})),
+            sorted_u32(),
+        ),
+        ("dup_delta_bitpack_transpose", dup_delta_bitpack, sorted_u32(1000)),
+        ("delta_bitpack_v3", delta_bitpack, sorted_u32()),
+        # a named fused_delta_bitpack whose deltas do not fit runs as
+        # delta -> bitpack on every backend; later edges shift past it
+        (
+            "fused_refused_wide",
+            pipeline("fused_delta_bitpack"),
+            numeric(rng.integers(0, 1 << 31, 2000).astype(np.uint32)),
+        ),
+        (
+            "fused_refused_then_zlib",
+            pipeline("fused_delta_bitpack", "zlib_backend"),
+            numeric(rng.integers(0, 1 << 31, 2000).astype(np.uint32)),
+        ),
     ]
+
+
+# cases compressed at an older wire format; every other case uses the default
+_CASE_CTX = {"delta_bitpack_v3": CompressionCtx(format_version=3)}
 
 
 @pytest.mark.parametrize("name,plan,stream", _routed_cases(), ids=lambda c: c if isinstance(c, str) else "")
 def test_host_device_frames_byte_identical(name, plan, stream):
+    ctx = _CASE_CTX.get(name)
     assert "device" in available_backends()
-    fh = compress(plan, stream, backend="host")
-    fd = compress(plan, stream, backend="device", )
+    fh = compress(plan, stream, backend="host", ctx=ctx)
+    fd = compress(plan, stream, backend="device", ctx=ctx)
     assert fh == fd, f"{name}: device frame differs from host frame"
     assert decompress(fd)[0].content_bytes() == stream.content_bytes()
-
-
-# -------------------------------------------------------------------- fusion
-def test_fusion_rewrites_adjacent_delta_bitpack():
-    x = sorted_u32()
-    frame = compress(pipeline("delta", "bitpack"), x, backend="device")
-    _, _, nodes, _ = read_frame(frame)
-    assert [n.codec_id for n in nodes] == [26], "expected one fused node"
-    assert decompress(frame)[0].content_bytes() == x.content_bytes()
-
-
-def test_fusion_falls_back_when_precondition_fails():
-    # wide wrapped deltas: the 32-bit-word kernel can't pack these profitably
-    x = numeric(rng.integers(0, 1 << 31, 2000).astype(np.uint32))
-    frame = compress(pipeline("delta", "bitpack"), x, backend="device")
-    _, _, nodes, _ = read_frame(frame)
-    assert [n.codec_id for n in nodes] == [3, 6], "must lower to delta+bitpack"
-    assert decompress(frame)[0].content_bytes() == x.content_bytes()
-
-
-def test_fusion_is_version_gated():
-    x = sorted_u32()
-    r = resolve(pipeline("delta", "bitpack"), x, CompressionCtx(format_version=3))
-    assert fuse_resolved(r) is r, "no fusion below wire format v4"
-    frame = execute(r, x, backend="device")
-    _, _, nodes, _ = read_frame(frame)
-    assert [n.codec_id for n in nodes] == [3, 6]
-
-
-def test_fusion_preserves_downstream_wiring():
-    # delta+bitpack followed by more nodes: edge renumbering must hold up
-    g = GraphBuilder(1)
-    a, b = g.add("dup", g.input(0))
-    d = g.add("delta", a)
-    g.add("bitpack", d)
-    g.add("transpose", b)
-    plan = g.build("fuse_mid")
-    x = sorted_u32(1000)
-    fd = compress(plan, x, backend="device")
-    _, _, nodes, _ = read_frame(fd)
-    assert 26 in [n.codec_id for n in nodes]
-    assert decompress(fd)[0].content_bytes() == x.content_bytes()
-    assert decompress(compress(plan, x, backend="host"))[0].content_bytes() == x.content_bytes()
-
-
-def test_fused_decode_matches_host_chain():
-    """decompress() is backend-free: both frame shapes regenerate the input."""
-    x = sorted_u32()
-    fh = compress(pipeline("delta", "bitpack"), x, backend="host")
-    fd = compress(pipeline("delta", "bitpack"), x, backend="device")
-    assert decompress(fh)[0].content_bytes() == decompress(fd)[0].content_bytes()
-    assert len(fd) <= len(fh), "fusion must not grow the frame"
-
-
-def test_fusion_declines_inexact_widths():
-    """Dynamic fusion only fires when the packing width is exact — rounding
-    3-bit deltas up to 4 would inflate the frame vs separate delta+bitpack."""
-    x = numeric(np.cumsum(rng.integers(0, 8, 2000)).astype(np.uint32))  # 3-bit
-    fh = compress(pipeline("delta", "bitpack"), x, backend="host")
-    fd = compress(pipeline("delta", "bitpack"), x, backend="device")
-    _, _, nodes, _ = read_frame(fd)
-    assert [n.codec_id for n in nodes] == [3, 6], "inexact width must not fuse"
-    assert fd == fh, "declined fusion falls back to the bit-identical pair"
+    codecs = [n.codec_id for n in read_frame(fd)[2]]
+    if name.startswith("fused_refused"):
+        assert codecs[:2] == [3, 6] and 26 not in codecs
+    elif name.startswith("fused"):
+        assert codecs == [26], "a plan naming fused_delta_bitpack writes codec 26"
+    elif "delta_bitpack" in name:
+        assert 26 not in codecs and codecs.count(3) == codecs.count(6) == 1
 
 
 def test_resolve_cache_bypass():
@@ -429,9 +414,15 @@ def test_session_counts_nodes_by_encoding_backend():
         "device": {"delta": 6, "transpose": 6},
         "host": {"zlib_backend": 6},
     }
+    # delta+bitpack stays two nodes: the deltas of x fit 8 bits, so both twins
+    # take them; 3-bit deltas leave bitpack to the host (widths dividing 32)
     with CompressorSession(pipeline("delta", "bitpack"), backend="device") as s:
         s.compress(x)
-    assert s.stats["nodes"] == {"device": {"fused_delta_bitpack": 1}}
+        s.compress(numeric(np.cumsum(rng.integers(0, 8, 2000)).astype(np.uint32)))
+    assert s.stats["nodes"] == {
+        "device": {"delta": 2, "bitpack": 1},
+        "host": {"bitpack": 1},
+    }
     with CompressorSession(plan) as host:
         host.compress(x)
     assert host.stats["nodes"] == {

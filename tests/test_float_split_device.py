@@ -26,6 +26,19 @@ SPECIAL = {
 SIZES = [0, 1, BLOCK - 1, BLOCK + 7, 3 * BLOCK + 5]
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _restore_split_counts():
+    """``float_split_info()`` counts for the whole process: leave it as this
+    module found it, so a later test file in the same worker (which compares
+    its fmt keys) sees no f16 device splits it did not make."""
+    before = floats.float_split_info()
+    yield
+    with floats._split_lock:
+        for backend, per in floats._split_elements.items():
+            per.clear()
+            per.update(before.get(backend, {}))
+
+
 def patterns(fmt: int, n: int) -> np.ndarray:
     """n bit patterns of format ``fmt``: the special values first, then
     random normal-range values."""

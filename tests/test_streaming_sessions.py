@@ -347,9 +347,9 @@ def test_cli_profiles_and_errors(tmp_path, capsys):
     assert main(["inspect", str(bad)]) == 2
 
 
-def test_session_pipeline_overlap_stats_and_prefetch_knob():
-    """The double-buffered window reports overlap accounting, and disabling
-    prefetch changes scheduling only — frames stay byte-identical."""
+def test_session_pipeline_overlap_stats_and_prefetch():
+    """The double-buffered window reports overlap accounting and changes
+    scheduling only — frames stay byte-identical to the one-shot call."""
     plan = pipeline("delta", "range_pack")
     data = numeric(np.arange(120000, dtype=np.uint32))
     oneshot = compress(plan, data, chunk_bytes=4096)
@@ -359,12 +359,6 @@ def test_session_pipeline_overlap_stats_and_prefetch_knob():
         assert st["prefetch_hits"] + st["prefetch_misses"] > 0
         assert st["draw_wait_s"] >= 0.0 and st["encode_wait_s"] >= 0.0
         assert st["max_inflight"] >= 1
-    with CompressorSession(
-        plan, chunk_bytes=4096, n_workers=2, prefetch=False
-    ) as sess:
-        assert sess.compress(data) == oneshot
-        st = sess.stats
-        assert st["prefetch_hits"] == 0 and st["prefetch_misses"] == 0
 
 
 def test_session_pipeline_prefetch_draws_overlap_lazy_source():
