@@ -2,8 +2,10 @@
 
 Copied verbatim from the seed implementations of ``codecs/lz.py`` and
 ``codecs/entropy.py`` (commit 09cade9) with codec registration stripped,
-and ``_tokenize_rows_ref``: ``codecs/numeric.py``'s tokenize encoder as it
-was while it grouped fixed-width rows with ``np.unique(axis=0)``.
+``_tokenize_rows_ref``: ``codecs/numeric.py``'s tokenize encoder as it
+was while it grouped fixed-width rows with ``np.unique(axis=0)``, and
+``_pack_bits``: its bit packer as it was while it scattered each value's
+bytes with ``np.bitwise_or.at`` (commit a3b64db).
 The cross-check suite (``test_vectorized_equiv.py``) pins the vectorized
 implementations against these: same inputs -> bit-identical output streams
 and headers, which is the wire-compatibility guarantee for every frame any
@@ -610,3 +612,29 @@ def _tokenize_rows_ref(streams, params):
     indices = numeric_stream(inv.astype(np.uint32))
     h = HeaderWriter().u8(0).u8(4).done()
     return [alphabet, indices], h
+
+
+def _pack_bits(vals: np.ndarray, bits: int) -> np.ndarray:
+    """Pack unsigned values (< 2^bits) LSB-first into bytes.  bits <= 57 so a
+    single unaligned 8-byte window always covers a value (see _unpack_bits)."""
+    if bits > 57:
+        raise ValueError("bitpack supports <= 57 bits per value; store instead")
+    n = vals.size
+    total_bits = n * bits
+    out = np.zeros((total_bits + 7) // 8 + 8, dtype=np.uint8)
+    offs = np.arange(n, dtype=np.int64) * bits
+    v = vals.astype(np.uint64)
+    # each value touches at most ceil(bits/8)+1 bytes
+    for b in range((bits + 7) // 8 + 1):
+        byte_idx = (offs >> 3) + b
+        shift = (np.int64(b) << 3) - (offs & 7)
+        pos = shift >= 0
+        # two-sided shift without UB: clamp each direction's amount to >= 0
+        contrib = np.where(
+            pos,
+            v >> np.where(pos, shift, 0).clip(max=63).astype(np.uint64),
+            v << np.where(~pos, -shift, 0).astype(np.uint64),
+        )
+        contrib = np.where(shift >= 64, 0, contrib)  # avoid x86 shift-mod-64 UB
+        np.bitwise_or.at(out, byte_idx, (contrib & 0xFF).astype(np.uint8))
+    return out[: (total_bits + 7) // 8]

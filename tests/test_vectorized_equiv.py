@@ -285,3 +285,129 @@ def test_tokenize_info_counts_paths():
         assert {k: after[k] - before[k] for k in after} == {
             k: int(k == path) for k in after
         }, (s, path)
+
+
+PACK_SIZES = (0, 1, 7, 8, 9, 4097, 65539)
+PACK_DTYPES = (np.uint8, np.uint16, np.uint32, np.uint64)
+
+
+@pytest.mark.parametrize("bits", range(1, 58))
+def test_pack_bits_matches_scatter_packer(bits):
+    """The 64-value group packer writes the scatter packer's bytes for every
+    width, length and input dtype, on random and all-max values, and
+    ``_unpack_bits`` reads the values back."""
+    rng = np.random.default_rng(bits)
+    for n in PACK_SIZES:
+        for dtype in PACK_DTYPES:
+            top = min(bits, 8 * np.dtype(dtype).itemsize)
+            cases = [rng.integers(0, 1 << top, n, dtype=np.uint64).astype(dtype)]
+            if top == bits:
+                cases.append(np.full(n, (1 << bits) - 1, dtype=dtype))
+            for vals in cases:
+                got = vec_numeric._pack_bits(vals, bits)
+                want = sr._pack_bits(vals, bits)
+                assert got.dtype == want.dtype == np.uint8
+                assert got.tobytes() == want.tobytes(), (n, dtype, bits)
+                back = vec_numeric._unpack_bits(got, bits, n, 8)
+                assert np.array_equal(back, vals.astype(np.uint64)), (n, dtype, bits)
+
+
+def test_pack_bits_refuses_over_57_bits():
+    vals = np.arange(10, dtype=np.uint64)
+    for pack in (vec_numeric._pack_bits, sr._pack_bits):
+        with pytest.raises(ValueError, match="<= 57 bits"):
+            pack(vals, 58)
+
+
+def _lineitem_like(rng, n):
+    """INT64 cents as TPC-H's l_quantity: whole units 1..50, times 100."""
+    return rng.integers(1, 51, n).astype(np.int64) * 100
+
+
+def _leaf_bits(rng, n, dtype):
+    import jax.numpy as jnp
+
+    leaf = np.asarray(jnp.asarray(0.02 * rng.normal(size=n), dtype))
+    return leaf.view(np.uint16 if dtype == "bfloat16" else np.uint32)
+
+
+FRAME_CASES = [
+    ("numeric", lambda rng: _lineitem_like(rng, 1 << 16), 0),
+    ("bfloat16", lambda rng: _leaf_bits(rng, 96 << 10, "bfloat16"), 64 << 10),
+    ("float32", lambda rng: _leaf_bits(rng, 48 << 10, "float32"), 64 << 10),
+]
+
+
+@pytest.mark.parametrize("backend", ["host", "device"])
+@pytest.mark.parametrize("profile,make,chunk", FRAME_CASES, ids=[c[0] for c in FRAME_CASES])
+def test_frames_unchanged_with_scatter_packer(monkeypatch, backend, profile, make, chunk):
+    """A lineitem-like column and bf16/fp32 checkpoint-like leaves, through
+    the benchmark's plans: the frame is sha256 for sha256 the one made with
+    the scatter packer, and the group packer ran on their range_pack nodes."""
+    import hashlib
+
+    from repro.codecs._util import device_available
+    from repro.codecs.profiles import resolve_profile_spec
+    from repro.core import CompressorSession, numeric
+
+    if backend == "device" and not device_available():
+        pytest.skip("no jax")
+    data = make(np.random.default_rng(zlib.crc32(profile.encode())))
+
+    def frame():
+        with CompressorSession(resolve_profile_spec(profile), backend=backend) as s:
+            digest = hashlib.sha256(s.compress(numeric(data), chunk_bytes=chunk)).hexdigest()
+            assert s.stats["nodes"]["host"]["range_pack"] >= 1
+        return digest
+
+    before = vec_numeric.pack_bits_info()["range_pack"]["calls"]
+    got = frame()
+    assert vec_numeric.pack_bits_info()["range_pack"]["calls"] > before
+    monkeypatch.setattr(vec_numeric, "_pack_bits", sr._pack_bits)
+    assert got == frame()
+
+
+def _pack_counts_after(encode):
+    before = vec_numeric.pack_bits_info()
+    encode()
+    after = vec_numeric.pack_bits_info()
+    return {c: {k: after[c][k] - before[c][k] for k in after[c]} for c in after}
+
+
+def test_pack_bits_info_counts_by_codec():
+    """One call and its values under the codec that packed them."""
+    x = numeric(np.arange(100, dtype=np.uint32))
+    encodes = {
+        "bitpack": lambda: vec_numeric._bitpack_enc([x], {}),
+        "range_pack": lambda: vec_numeric._range_pack_enc([x], {}),
+        "fused_delta_bitpack": lambda: vec_numeric._fused_enc([x], {}),
+    }
+    for codec, encode in encodes.items():
+        assert _pack_counts_after(encode) == {
+            c: {"calls": int(c == codec), "values": 100 * (c == codec)} for c in encodes
+        }, codec
+
+
+def test_pack_bits_info_sums_across_threads():
+    """Concurrent encodes from 4 threads, switching as often as the
+    interpreter allows, lose no count."""
+    import sys
+    from concurrent.futures import ThreadPoolExecutor
+
+    x = numeric(np.arange(5000, dtype=np.uint16))
+
+    def work(_):
+        for _ in range(25):
+            vec_numeric._range_pack_enc([x], {})
+
+    def encode():
+        with ThreadPoolExecutor(4) as pool:
+            list(pool.map(work, range(4), timeout=60))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        counts = _pack_counts_after(encode)
+    finally:
+        sys.setswitchinterval(interval)
+    assert counts["range_pack"] == {"calls": 100, "values": 500_000}
